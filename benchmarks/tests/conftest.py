@@ -1,0 +1,16 @@
+"""CPU tests of the benchmark at tiny sizes.
+
+    JAX_PLATFORMS=cpu python -m pytest benchmarks/tests -q
+
+These tests never look for a chip.
+"""
+
+import os
+import sys
+
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
+
+ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+if ROOT not in sys.path:
+    sys.path.insert(0, ROOT)
