@@ -27,7 +27,7 @@ import numpy as np  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 
 from spark_rapids_jni_tpu import (  # noqa: E402
-    Column, INT32, STRING, Table,
+    Column, INT32, STRING, Table, obs,
 )
 from spark_rapids_jni_tpu.ops import (  # noqa: E402
     convert_from_rows, convert_to_rows, get_json_object, interleave_bits,
@@ -38,14 +38,13 @@ from spark_rapids_jni_tpu.parquet import (  # noqa: E402
 )
 from spark_rapids_jni_tpu.models import distributed_q72_step  # noqa: E402
 from spark_rapids_jni_tpu.parallel import make_mesh  # noqa: E402
-from spark_rapids_jni_tpu.utils import metrics  # noqa: E402
 from spark_rapids_jni_tpu.utils.datagen import (  # noqa: E402
     DataProfile, create_random_table,
 )
 
 
 def main():
-    metrics.enable()
+    obs.enable()   # spans record, and feed the obs metrics registry
     rng = np.random.default_rng(7)
 
     # 1. parquet footer: parse + prune a footer to the columns we read
@@ -238,10 +237,12 @@ def main():
         ['{"a":[{"b":1},{"c":9},{"b":2}]}', '{"a":[]}'])
     print("$.a[*].b ->", get_json_object(jcol, "$.a[*].b").to_pylist())
 
-    # 14. operator metrics
-    snap = metrics.snapshot()
-    print("metrics:", {k: v for k, v in sorted(snap.items())
-                       if k.endswith(".calls") or k.endswith(".rows")})
+    # 14. operator metrics: the span families of the obs registry
+    snap = obs.metrics.registry().snapshot()
+    for family in ("srj_tpu_span_calls_total", "srj_tpu_span_rows_total",
+                   "srj_tpu_span_bytes_total"):
+        print(family + ":", dict(sorted(
+            snap.get(family, {}).get("values", {}).items())))
 
 
 if __name__ == "__main__":

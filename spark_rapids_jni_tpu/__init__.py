@@ -24,7 +24,7 @@ Subpackage map (see each module's docstring for its reference citation):
   points (wall + fenced device time, rows/bytes, per-span XLA compile
   counts, failure capture), a JSONL event sink (``SRJ_TPU_EVENTS=<path>``),
   and the ``python -m spark_rapids_jni_tpu.obs`` report CLI; the NVTX-range
-  layer it subsumes lives in ``utils.tracing``/``utils.metrics``.
+  layer it subsumes lives in ``utils.tracing``.
 - ``memory``: the RMM analogue — pooled host staging arena (native
   freelist, ``native/src/host_arena.cpp``) + PJRT device-buffer
   statistics/lifetime adaptor (reference RMM knobs,

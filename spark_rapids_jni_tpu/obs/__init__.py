@@ -3,13 +3,15 @@
 The reference instruments every footer API and kernel hot spot with NVTX
 ranges (``CUDF_FUNC_RANGE()``, ``NativeParquetJni.cpp:136,392``) and ships
 a fault-observation tool (``faultinj.cu``); profile-guided rounds hang off
-that substrate.  This package is the TPU-native equivalent, subsuming and
-extending ``utils/tracing.py`` (named scopes) and ``utils/metrics.py``
-(counters):
+that substrate.  This package is the TPU-native equivalent, extending
+``utils/tracing.py`` (named scopes):
 
 - :func:`span` / :func:`span_fn` — timed spans on every hot entry point:
   host wall-clock, device-completion time (``block_until_ready`` fence),
   nesting, thread identity, rows/bytes attributes, exception capture.
+  Under any ``jax.profiler`` capture every span is also an ``srj/<name>``
+  annotation on the capture's host plane; :func:`launch` spans sit at
+  each program launch of the hash and row paths.
 - :mod:`~spark_rapids_jni_tpu.obs.compilemon` — ``jax.monitoring``
   subscription counting XLA backend compiles (and compile-seconds) per
   span, so shape-churn recompiles are a visible counter, not a mystery
@@ -55,7 +57,8 @@ import os as _os
 
 from spark_rapids_jni_tpu.obs.spans import (  # noqa: F401
     Span, clear, configure_sink, current_span, disable, dropped, emit,
-    enable, enabled, events, flush, recording, sink_path, span, span_fn,
+    enable, enabled, events, flush, launch, recording, sink_path, span,
+    span_fn,
 )
 from spark_rapids_jni_tpu.obs import compilemon as _compilemon
 from spark_rapids_jni_tpu.obs import context  # noqa: F401

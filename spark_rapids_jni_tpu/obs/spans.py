@@ -17,12 +17,20 @@ consumes.
 
 Off by default and **free when off**: the disabled path is one attribute
 read, inserts no device fences, and takes no locks — the same contract as
-``metrics``/``tracing`` (and the acceptance guard in
+``tracing`` (and the acceptance guard in
 ``tests/test_obs.py::test_disabled_spans_insert_no_fences``).  Enable with
 ``SRJ_TPU_EVENTS=<path>`` (JSONL sink), ``SRJ_TPU_OBS=1`` (ring only), or
 :func:`enable`.  Spans also stand down inside a jit trace (recording there
-would fire once per compile, not per call, and a tracer cannot be fenced) —
-the same eager-only rule ``metrics._recording`` enforces.
+would fire once per compile, not per call, and a tracer cannot be fenced).
+
+Whenever a ``jax.profiler`` capture is running, every span — recorded or
+not — also opens a ``jax.profiler.TraceAnnotation`` named
+``srj/<span name>`` carrying its attributes as metadata, so the program's
+spans lie on the capture's host plane on the same clock as the device's
+"XLA Ops" and "XLA Modules" lines.  That path never fences and never
+reads memory.  :func:`launch` is the span around one call that hands one
+program to the runtime; its ``program`` attribute is the XLA module name
+the launch runs as.
 """
 
 from __future__ import annotations
@@ -37,12 +45,17 @@ import time
 from typing import Dict, List, Optional
 
 import jax
+from jax.profiler import TraceAnnotation as _TraceAnnotation
 
 from spark_rapids_jni_tpu.utils import metrics as _metrics
 from spark_rapids_jni_tpu.obs import context as _context
 from spark_rapids_jni_tpu.obs.metrics import observe_event as _observe_event
 
 _RING_CAP = int(os.environ.get("SRJ_TPU_OBS_RING", "4096"))
+
+#: prefix of every span's name in a profiler capture
+TRACE_PREFIX = "srj/"
+_capture_on = _TraceAnnotation.is_enabled
 
 
 class _State:
@@ -103,6 +116,12 @@ def recording() -> bool:
     eagerly (inside a jit trace a span body runs once per compile, not per
     invocation, and tracers cannot be fenced)."""
     return _STATE.enabled and _metrics.eager()
+
+
+def capturing() -> bool:
+    """True when spans should annotate a running profiler capture here
+    and now: a capture is on AND executing eagerly."""
+    return _capture_on() and _metrics.eager()
 
 
 def configure_sink(path: Optional[str]) -> None:
@@ -287,7 +306,7 @@ class Span:
 
     __slots__ = ("name", "attrs", "depth", "parent", "t0", "_fence_t",
                  "compiles", "compile_s", "_mem0", "span_id", "trace_id",
-                 "parent_span_id", "tenant")
+                 "parent_span_id", "tenant", "_tm")
 
     def __init__(self, name: str, attrs: Dict, depth: int,
                  parent: Optional[str]):
@@ -304,9 +323,12 @@ class Span:
         self.trace_id = None
         self.parent_span_id = None
         self.tenant = None
+        self._tm = None
 
     def set(self, **attrs) -> None:
         self.attrs.update(attrs)
+        if self._tm is not None:
+            self._tm.set_metadata(**attrs)
 
     def fence(self, value):
         """Block until every array in ``value`` is device-complete and
@@ -332,24 +354,77 @@ class _NullSpan:
 
 
 _NULL_SPAN = _NullSpan()
+_OFF = contextlib.nullcontext(_NULL_SPAN)
 
 
-@contextlib.contextmanager
+class _Annotation:
+    """A span that is not recorded but annotates a running profiler
+    capture: ``set`` adds metadata to the annotation, ``fence`` does
+    not block."""
+
+    __slots__ = ("_tm",)
+
+    def __init__(self, name: str, attrs: Dict):
+        self._tm = _TraceAnnotation(TRACE_PREFIX + name, **attrs)
+
+    def __enter__(self):
+        self._tm.__enter__()
+        return self
+
+    def __exit__(self, *exc):
+        self._tm.__exit__(*exc)
+        return False
+
+    def set(self, **attrs) -> None:
+        self._tm.set_metadata(**attrs)
+
+    def fence(self, value):
+        return value
+
+
 def span(name: str, **attrs):
     """Context manager timing a block as one span event.
 
-    Yields the active :class:`Span` (or a no-op stand-in when not
-    recording).  On exception the event records ``status="error"`` with
-    the exception type/message and device health, then re-raises."""
-    if not recording():
-        yield _NULL_SPAN
-        return
+    Yields the active :class:`Span` (or a stand-in when not recording:
+    one that annotates a running profiler capture, else a no-op).  On
+    exception the event records ``status="error"`` with the exception
+    type/message and device health, then re-raises."""
+    if recording():
+        return _recorded(name, attrs)
+    if capturing():
+        return _Annotation(name, attrs)
+    return _OFF
+
+
+def launch(name: str, fn, **attrs):
+    """Span around one call that hands one program to the runtime.
+    ``fn`` is the jitted callable the call launches (or the function it
+    jits); the span's ``program`` attribute is the XLA module name that
+    launch runs as, :func:`program_name` of ``fn``."""
+    if not (_STATE.enabled or _capture_on()):
+        return _OFF
+    return span(name, program=program_name(fn), **attrs)
+
+
+def program_name(fn) -> str:
+    """The XLA module a jitted callable runs as: ``jit_<function name>``."""
+    return "jit_" + fn.__name__
+
+
+@contextlib.contextmanager
+def _recorded(name: str, attrs: Dict):
     stack = _stack()
     sp = Span(name, attrs, depth=len(stack),
               parent=stack[-1].name if stack else None)
-    sp._mem0 = _mem_snapshot()
-    if sp._mem0:
-        _reset_peak()
+    if not stack:
+        # only an outermost span reads memory and resets the peak: a
+        # child's reset would erase the peak its parent reports
+        sp._mem0 = _mem_snapshot()
+        if sp._mem0:
+            _reset_peak()
+    if _capture_on():
+        sp._tm = _TraceAnnotation(TRACE_PREFIX + name, **attrs)
+        sp._tm.__enter__()
     # request-scoped causality: under an active TraceContext the span
     # joins that request's trace and becomes the parent of whatever its
     # body starts — including work handed to other threads via
@@ -375,6 +450,8 @@ def span(name: str, **attrs):
         stack.pop()
         if ctx_token is not None:
             _context._reset(ctx_token)
+        if sp._tm is not None:
+            sp._tm.__exit__(None, None, None)
 
 
 def _finish(sp: Span, status: str, err: Optional[BaseException] = None
@@ -391,7 +468,7 @@ def _finish(sp: Span, status: str, err: Optional[BaseException] = None
         ev["compiles"] = sp.compiles
         ev["compile_s"] = sp.compile_s
     ev.update(sp.attrs)
-    mem1 = _mem_snapshot()
+    mem1 = _mem_snapshot() if sp.depth == 0 else None
     if mem1:
         mem = {"bytes_in_use": mem1.get("bytes_in_use"),
                "peak_bytes_in_use": mem1.get("peak_bytes_in_use")}
@@ -437,23 +514,30 @@ def span_fn(name: Optional[str] = None, attrs=None, fence: bool = True):
     are swallowed — attributes are best-effort, timing is not.
     ``fence=False`` for host-only functions (no arrays to wait on).
 
-    When not recording (disabled, or inside a jit trace) the wrapper is a
-    single predicate check and a tail call — no fence, no lock."""
+    When not recording (disabled, or inside a jit trace) and no profiler
+    capture is running, the wrapper is two predicate checks and a tail
+    call — no fence, no lock.  Under a capture alone it annotates the
+    call and does not fence."""
 
     def deco(fn):
         sname = name or fn.__name__
 
+        def extract(args, kwargs):
+            if attrs is None:
+                return {}
+            try:
+                return attrs(*args, **kwargs) or {}
+            except Exception:
+                return {}
+
         @functools.wraps(fn)
         def wrapper(*args, **kwargs):
             if not recording():
-                return fn(*args, **kwargs)
-            a = {}
-            if attrs is not None:
-                try:
-                    a = attrs(*args, **kwargs) or {}
-                except Exception:
-                    a = {}
-            with span(sname, **a) as sp:
+                if not capturing():
+                    return fn(*args, **kwargs)
+                with _Annotation(sname, extract(args, kwargs)):
+                    return fn(*args, **kwargs)
+            with span(sname, **extract(args, kwargs)) as sp:
                 out = fn(*args, **kwargs)
                 if fence:
                     sp.fence(out)
@@ -470,7 +554,7 @@ def current_span() -> Optional[Span]:
     return stack[-1] if stack else None
 
 
-# env-driven bring-up (the SRJ_METRICS / SRJ_TPU_TRACE pattern):
+# env-driven bring-up (the SRJ_TPU_TRACE pattern):
 # SRJ_TPU_EVENTS=<path> enables recording with a JSONL sink;
 # SRJ_TPU_OBS=1 enables the ring buffer alone.
 _env_sink = os.environ.get("SRJ_TPU_EVENTS")

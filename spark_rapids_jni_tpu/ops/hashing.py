@@ -37,7 +37,7 @@ from spark_rapids_jni_tpu.table import (
     Column, Table, column_nbytes,
     bytes2d_to_words as _bytes_to_u32_lanes,
 )
-from spark_rapids_jni_tpu.obs import span_fn
+from spark_rapids_jni_tpu.obs import span_fn, spans
 from spark_rapids_jni_tpu.runtime import shapes
 from spark_rapids_jni_tpu.utils import tracing
 
@@ -394,13 +394,15 @@ def _dispatch_hash(op: str, pcols, seed: int, Wb: int, xla_jit):
 
         def _twin(cols):
             pallas_kernels.stamp_impl("xla")
-            return xla_jit(cols, seed, Wb)
+            with spans.launch("hash.xla", xla_jit):
+                return xla_jit(cols, seed, Wb)
 
         from spark_rapids_jni_tpu.runtime import resilience
         return resilience.run(op, _primary, pcols, sig=sig, bucket=b,
                               impl="pallas", fallback=_twin)
     pallas_kernels.stamp_impl("xla")
-    return xla_jit(pcols, seed, Wb)
+    with spans.launch("hash.xla", xla_jit):
+        return xla_jit(pcols, seed, Wb)
 
 
 @span_fn(attrs=_hash_attrs)
@@ -422,8 +424,6 @@ def murmur3_hash(table_or_cols, seed: int = DEFAULT_SEED,
     cols = (table_or_cols.columns if isinstance(table_or_cols, Table)
             else tuple(table_or_cols))
     n = cols[0].num_rows
-    from spark_rapids_jni_tpu.utils import metrics
-    metrics.op("murmur3_hash", rows=n)
     W = _resolve_str_window(cols, max_str_len) \
         if any(c.dtype.is_string for c in cols) else 0
     plan = _hash_bucketed(cols, bucket, W)
@@ -441,6 +441,7 @@ def murmur3_hash(table_or_cols, seed: int = DEFAULT_SEED,
         return shapes.unpad_array(out, n)
 
 
+@span_fn(attrs=lambda hashes, divisor: {"rows": hashes.shape[0]})
 def pmod(hashes: jnp.ndarray, divisor: int) -> jnp.ndarray:
     """Spark's positive-mod used by HashPartitioning."""
     m = hashes % jnp.int32(divisor)

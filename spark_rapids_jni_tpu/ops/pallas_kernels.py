@@ -635,10 +635,28 @@ def _mm3_call(pieces, h, desc, interpret: bool) -> jnp.ndarray:
 def _chain_calls(call, mode: str, cols, h, W: int, interpret: bool):
     """Run a hash kernel over ``cols`` in :func:`_hash_runs`, each call
     chaining from the last one's running hash ``h``."""
-    for run in _hash_runs(cols, W, mode):
-        h = call(tuple(_hash_prep(c, W, mode) for c in run), h,
-                 _hash_desc(run, W, mode), interpret)
+    column = 0
+    for i, run in enumerate(_hash_runs(cols, W, mode)):
+        pieces = []
+        for c in run:
+            with spans.launch("hash.prep", _hash_prep, column=column):
+                pieces.append(_hash_prep(c, W, mode))
+            column += 1
+        desc = _hash_desc(run, W, mode)
+        with spans.launch("hash.kernel", call, run=i,
+                          words=_desc_rows(desc)):
+            h = call(tuple(pieces), h, desc, interpret)
     return h
+
+
+@functools.partial(jax.jit, static_argnums=(0,))
+def _mm3_seed(n: int, seed) -> jnp.ndarray:
+    return jnp.full((1, n), seed, jnp.uint32)
+
+
+@jax.jit
+def _mm3_finish(h) -> jnp.ndarray:
+    return jax.lax.bitcast_convert_type(h[0], jnp.int32)
 
 
 def murmur3_cols(cols, seed: int, *, W: int = 0,
@@ -646,11 +664,15 @@ def murmur3_cols(cols, seed: int, *, W: int = 0,
     """Spark murmur3 chain over fixed-width AND dense-padded string
     columns, one VMEM tile of rows per grid step.  ``W`` is the
     bucketed char-window width shared by all string columns (0 when
-    none).  Bit-exact with ``hashing._murmur3_chain``."""
+    none).  Bit-exact with ``hashing._murmur3_chain``.  The seed row
+    and the final bitcast are one program each, so each launch is one
+    ``launch`` span."""
     cols = tuple(cols)
-    h = jnp.full((1, cols[0].num_rows), np.uint32(seed), jnp.uint32)
+    with spans.launch("hash.seed", _mm3_seed):
+        h = _mm3_seed(cols[0].num_rows, np.uint32(seed))
     h = _chain_calls(_mm3_call, "mm3", cols, h, int(W), interpret)
-    return jax.lax.bitcast_convert_type(h[0], jnp.int32)
+    with spans.launch("hash.finish", _mm3_finish):
+        return _mm3_finish(h)
 
 
 #: historical fixed-only entry point; the generalized kernel accepts
@@ -797,10 +819,22 @@ def xxhash64_cols(cols, seed: int, *, W: int = 0,
     ``W`` is the bucketed char-window width shared by all string
     columns (0 when none).  Bit-exact with ``hashing._xx64_chain``."""
     cols = tuple(cols)
-    n = cols[0].num_rows
-    h = jnp.stack([jnp.full((n,), np.uint32(seed), jnp.uint32),
-                   jnp.zeros((n,), jnp.uint32)])
-    return _chain_calls(_xx64_call, "xx64", cols, h, int(W), interpret).T
+    with spans.launch("hash.seed", _xx64_seed):
+        h = _xx64_seed(cols[0].num_rows, np.uint32(seed))
+    h = _chain_calls(_xx64_call, "xx64", cols, h, int(W), interpret)
+    with spans.launch("hash.finish", _xx64_finish):
+        return _xx64_finish(h)
+
+
+@functools.partial(jax.jit, static_argnums=(0,))
+def _xx64_seed(n: int, seed) -> jnp.ndarray:
+    return jnp.stack([jnp.full((n,), seed, jnp.uint32),
+                      jnp.zeros((n,), jnp.uint32)])
+
+
+@jax.jit
+def _xx64_finish(h) -> jnp.ndarray:
+    return h.T
 
 
 #: historical fixed-only entry point; the generalized kernel accepts

@@ -46,7 +46,6 @@ from spark_rapids_jni_tpu.ops.row_layout import (
     JCUDF_ROW_ALIGNMENT, MAX_BATCH_BYTES, RowLayout, compute_row_layout,
 )
 from spark_rapids_jni_tpu.utils.tracing import func_range
-from spark_rapids_jni_tpu.utils import metrics
 from spark_rapids_jni_tpu.utils import tracing
 from spark_rapids_jni_tpu.obs import span_fn
 from spark_rapids_jni_tpu.obs import spans as _obs_spans
@@ -542,7 +541,6 @@ def _convert_to_rows_impl(table: Table, size_limit: int,
                           use_pallas: Optional[bool],
                           impl: Optional[str]) -> List[RowsColumn]:
     layout = compute_row_layout(table.dtypes)
-    metrics.op("convert_to_rows", rows=table.num_rows)
     if layout.has_strings:
         if all(c.is_padded for c in _string_cols(table)):
             return _to_rows_variable_padded(table, layout, size_limit)
@@ -596,7 +594,7 @@ def _convert_to_rows_impl(table: Table, size_limit: int,
             interp = platform != "tpu"
             sig = (layout.num_columns, layout.fixed_row_size)
             b = size if size is not None else n
-            st = jnp.int32(start)
+            st = np.int32(start)   # a transfer, not a program
             leaves, treedef = jax.tree_util.tree_flatten(table)
             pallas_kernels.register(
                 "convert_to_rows", sig, b,
@@ -620,7 +618,16 @@ def _convert_to_rows_impl(table: Table, size_limit: int,
         if impl == "mxu":
             from spark_rapids_jni_tpu.ops import row_mxu
             return row_mxu.to_rows_fixed(table, layout, start, size)
-        return _to_rows_fixed_jit(table, layout, jnp.int32(start), size)
+        return _to_rows_fixed_jit(table, layout, np.int32(start), size)
+
+    def encode_program(size):
+        # the jitted callable each engine's encode launches
+        if impl == "pallas":
+            return (pallas_kernels._to_rows_planes_jit if size is None
+                    else pallas_kernels._to_rows_planes_batch_jit)
+        if impl == "mxu":
+            return row_mxu._to_rows_mxu_jit
+        return _to_rows_fixed_jit
 
     if len(plan_fixed_batches(n, layout.fixed_row_size, chunk)) == 1:
         # host-built (jnp.asarray of numpy emits no XLA compile): batch
@@ -628,7 +635,9 @@ def _convert_to_rows_impl(table: Table, size_limit: int,
         # operator's compiled-program budget (see runtime/shapes.py)
         offsets = jnp.asarray(
             np.arange(n + 1, dtype=np.int32) * layout.fixed_row_size)
-        return [RowsColumn(encode(), offsets)]
+        with _obs_spans.launch("rows.encode", encode_program(None),
+                               batch=0, rows=n):
+            return [RowsColumn(encode(), offsets)]
     # multi-batch: encode per batch (sliced inside the jit with a traced
     # start) so peak memory stays ~one batch of transients, the way the
     # reference converts per row-batch (row_conversion.cu:1768-1830).
@@ -639,11 +648,14 @@ def _convert_to_rows_impl(table: Table, size_limit: int,
     per = min((-(-n // nb) + 31) // 32 * 32,
               chunk // layout.fixed_row_size // 32 * 32)
     out = []
-    for start in range(0, n, per):
+    for batch, start in enumerate(range(0, n, per)):
         size = min(per, n - start)
         offsets = jnp.asarray(
             np.arange(size + 1, dtype=np.int32) * layout.fixed_row_size)
-        out.append(RowsColumn(encode(start, size), offsets))
+        with _obs_spans.launch("rows.encode", encode_program(size),
+                               batch=batch, rows=size):
+            data = encode(start, size)
+        out.append(RowsColumn(data, offsets))
     return out
 
 
@@ -686,8 +698,6 @@ def _convert_from_rows_impl(rows: RowsColumn, dtypes: Sequence[DType],
                             layout: RowLayout,
                             use_pallas: Optional[bool],
                             impl: Optional[str]) -> Table:
-    metrics.op("convert_from_rows", rows=rows.num_rows,
-               bytes_=rows.data.size)
     if layout.has_strings:
         if rows.is_padded:
             return _from_rows_variable_padded(rows, layout)
@@ -762,7 +772,6 @@ def convert_to_rows_grouped(gc, *, size_limit: int = MAX_BATCH_BYTES
     from spark_rapids_jni_tpu.ops import row_mxu
     layout = gc.layout
     n = gc.num_rows
-    metrics.op("convert_to_rows_grouped", rows=n)
     rs = layout.fixed_row_size
     align = row_mxu._FUSE_TILE
     chunk = min(size_limit, MAX_BATCH_BYTES)
@@ -784,14 +793,33 @@ def convert_to_rows_grouped(gc, *, size_limit: int = MAX_BATCH_BYTES
     per = min((-(-n // nb) + align - 1) // align * align, per_max)
     out = []
     platform = _platform_of(gc.planes)
-    for start in range(0, n, per):
+    for batch, start in enumerate(range(0, n, per)):
         size = min(per, n - start)
-        offsets = jnp.arange(size + 1, dtype=jnp.int32) * rs
-        out.append(RowsColumn(
-            row_mxu.to_rows_fixed_grouped(gc, start, size,
-                                          interpret=platform != "tpu"),
-            offsets))
+        # the offsets are the same two programs an eager
+        # ``jnp.arange(size + 1) * rs`` runs, one launch span each; the
+        # rebinding drops the iota before the encode allocates, as the
+        # eager expression dropped its temporary
+        with _obs_spans.launch("rows.offsets", _row_iota, batch=batch):
+            offsets = _row_iota(size + 1)
+        with _obs_spans.launch("rows.offsets", _row_scale, batch=batch):
+            offsets = _row_scale(offsets, rs)
+        with _obs_spans.launch("rows.encode", row_mxu._grouped_encode_impl,
+                               batch=batch, rows=size):
+            data = row_mxu.to_rows_fixed_grouped(
+                gc, start, size, interpret=platform != "tpu")
+        out.append(RowsColumn(data, offsets))
     return out
+
+
+@functools.partial(jax.jit, static_argnums=0)
+def _row_iota(n: int) -> jnp.ndarray:
+    return jnp.arange(n, dtype=jnp.int32)
+
+
+@jax.jit
+def _row_scale(index, row_size) -> jnp.ndarray:
+    """``index * row_size``: a batch's row offsets."""
+    return index * row_size
 
 
 @span_fn(attrs=lambda rows, dtypes: {"rows": rows.num_rows,
@@ -810,8 +838,6 @@ def convert_from_rows_grouped(rows: RowsColumn, dtypes: Sequence[DType]):
     if layout.has_strings:
         raise ValueError("grouped decode covers fixed-width tables; "
                          "string tables use convert_from_rows")
-    metrics.op("convert_from_rows_grouped", rows=rows.num_rows,
-               bytes_=rows.data.size)
     from spark_rapids_jni_tpu.ops import row_mxu
     return row_mxu.from_rows_fixed_grouped(rows.data, layout)
 

@@ -47,6 +47,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from spark_rapids_jni_tpu.obs import spans
 from spark_rapids_jni_tpu.table import Column, DType, Table, pack_bools_2d
 from spark_rapids_jni_tpu.ops.row_layout import RowLayout
 
@@ -410,7 +411,7 @@ def to_rows_fixed(table: Table, layout: RowLayout,
             platform = _platform_of_table(table)
             pack = "pallas" if platform == "tpu" else "xla"
     return _to_rows_mxu_jit(table, layout, _forward_p3_device(layout),
-                            jnp.int32(start), size, pack)
+                            np.int32(start), size, pack)
 
 
 # ---------------------------------------------------------------------------
@@ -670,7 +671,8 @@ def table_to_grouped(table: Table, layout: RowLayout = None):
     if layout is None:
         from spark_rapids_jni_tpu.ops.row_layout import compute_row_layout
         layout = compute_row_layout(table.dtypes)
-    planes, vmask = _pack_grouped_jit(table, layout)
+    with spans.launch("rows.pack", _pack_grouped_jit):
+        planes, vmask = _pack_grouped_jit(table, layout)
     return GroupedColumns(planes, vmask, layout)
 
 

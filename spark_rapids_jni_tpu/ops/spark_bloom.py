@@ -177,7 +177,7 @@ def might_contain_device(bf: SparkBloomFilter, col: Column, *,
     from spark_rapids_jni_tpu.ops import hashing as H
     from spark_rapids_jni_tpu.ops import pallas_kernels
     from spark_rapids_jni_tpu.runtime import shapes
-    from spark_rapids_jni_tpu.utils import metrics, tracing
+    from spark_rapids_jni_tpu.utils import tracing
     from spark_rapids_jni_tpu.obs import spans
 
     if col.dtype.kind not in _DEVICE_KINDS or col.children:
@@ -189,7 +189,6 @@ def might_contain_device(bf: SparkBloomFilter, col: Column, *,
     num_bits = bf.num_bits
     with spans.span("bloom_might_contain", rows=n,
                     bytes=n * col.dtype.itemsize) as sp:
-        metrics.op("bloom_might_contain", rows=n)
         if num_bits >= 1 << 31:
             sp.set(impl="host")
             return jnp.asarray(bf.might_contain(col))

@@ -872,9 +872,6 @@ def shuffle_table_sharded(table: Table, key_cols: Sequence[int],
                 table, key_cols, mesh, axis_name, seed, method, layout,
                 slot_starts, fe_pad, row_size, widths, num_parts, sp)
         sp.fence((result.rows, result.num_valid))
-    from spark_rapids_jni_tpu.utils import metrics
-    metrics.op("shuffle_table_sharded", rows=table.num_rows,
-               bytes_=table.num_rows * row_size)
     return result
 
 

@@ -1,44 +1,15 @@
-"""Structured operator metrics (observability layer).
+"""The eager-execution probe that every recording call site checks.
 
-The reference's observability is slf4j logging plus NVTX ranges; the
-framework-level counterpart here is a process-local metrics registry:
-every public operator entry point records invocation counts and row/byte
-volumes.  Off by default (one dict lookup + branch per call); enable with
-``SRJ_METRICS=1`` or :func:`enable`.
-
-Usage::
-
-    from spark_rapids_jni_tpu.utils import metrics
-    metrics.enable()
-    ... run operators ...
-    print(metrics.snapshot())
-    # {'convert_to_rows.calls': 3, 'convert_to_rows.rows': 3000000, ...}
+Spans (``obs/spans.py``), the shape policy, resilience and the plan
+executor record only when executing eagerly: inside a jit trace a call
+site's Python runs once per compile, not once per invocation, so
+recording there would under-count (and cached traces record nothing at
+all).  Operator counters live in the obs registry
+(``srj_tpu_span_calls_total``, ``srj_tpu_span_rows_total``,
+``srj_tpu_span_bytes_total``), fed by span completion.
 """
 
 from __future__ import annotations
-
-import os
-import threading
-from typing import Dict
-
-_lock = threading.Lock()
-_counters: Dict[str, int] = {}
-_enabled = os.environ.get("SRJ_METRICS", "0") == "1"
-
-
-def enable() -> None:
-    global _enabled
-    _enabled = True
-
-
-def disable() -> None:
-    global _enabled
-    _enabled = False
-
-
-def enabled() -> bool:
-    return _enabled
-
 
 # trace-state probe, resolved once: ``jax.core.trace_state_clean`` has
 # churned across jax releases (moved under jax._src.core; re-exported via
@@ -75,43 +46,3 @@ def eager() -> bool:
         return bool(probe())
     except Exception:
         return False
-
-
-def _recording() -> bool:
-    """Enabled AND not inside a jit trace: a traced call site executes its
-    Python once per compile, not once per invocation, so recording there
-    would under-count (and cached traces record nothing at all)."""
-    return _enabled and eager()
-
-
-def count(name: str, value: int = 1) -> None:
-    """Add ``value`` to counter ``name`` (no-op unless enabled)."""
-    if not _recording():
-        return
-    with _lock:
-        _counters[name] = _counters.get(name, 0) + int(value)
-
-
-def op(name: str, rows: int = 0, bytes_: int = 0) -> None:
-    """Record one operator invocation with row/byte volume (eager call
-    sites only — see :func:`_recording`)."""
-    if not _recording():
-        return
-    with _lock:
-        _counters[f"{name}.calls"] = _counters.get(f"{name}.calls", 0) + 1
-        if rows:
-            _counters[f"{name}.rows"] = \
-                _counters.get(f"{name}.rows", 0) + int(rows)
-        if bytes_:
-            _counters[f"{name}.bytes"] = \
-                _counters.get(f"{name}.bytes", 0) + int(bytes_)
-
-
-def snapshot() -> Dict[str, int]:
-    with _lock:
-        return dict(_counters)
-
-
-def reset() -> None:
-    with _lock:
-        _counters.clear()

@@ -9,9 +9,9 @@ TensorBoard-loadable profile.
 
 Toggle: ``SRJ_TPU_TRACE=0`` (the ``ai.rapids.cudf.nvtx.enabled`` analogue)
 or :func:`disable` / :func:`enable` — the decision is read per call, so a
-process can turn scoping on around one suspect region and back off, same
-as :mod:`~spark_rapids_jni_tpu.utils.metrics`.  Structured timing/failure
-telemetry lives one layer up in :mod:`spark_rapids_jni_tpu.obs`.
+process can turn scoping on around one suspect region and back off.
+Structured timing/failure telemetry lives one layer up in
+:mod:`spark_rapids_jni_tpu.obs`.
 """
 
 from __future__ import annotations

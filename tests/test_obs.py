@@ -15,7 +15,8 @@ import pytest
 from spark_rapids_jni_tpu import Column, INT32, Table, faultinj, obs
 from spark_rapids_jni_tpu.obs import report
 from spark_rapids_jni_tpu.ops import convert_from_rows, convert_to_rows
-from spark_rapids_jni_tpu.ops.hashing import murmur3_hash
+from spark_rapids_jni_tpu.obs import spans
+from spark_rapids_jni_tpu.ops.hashing import murmur3_hash, pmod
 from spark_rapids_jni_tpu.utils import metrics
 
 
@@ -219,11 +220,11 @@ def test_faultinj_trap_produces_fault_and_span_events(obs_on):
 # ---------------------------------------------------------------------------
 
 def test_disabled_spans_insert_no_fences(monkeypatch):
-    """With obs off (and metrics off), instrumented operators must add
-    ZERO ``jax.block_until_ready`` fences — disabled observability cannot
-    change dispatch/synchronization behavior (acceptance criterion)."""
+    """With obs off and no profiler capture running, instrumented
+    operators must add ZERO ``jax.block_until_ready`` fences and open no
+    profiler annotation — disabled observability cannot change
+    dispatch/synchronization behavior (acceptance criterion)."""
     obs.disable()
-    metrics.disable()
     t = _int_table()
     # warm everything first so the instrumented calls below do no lazy
     # init that might legitimately fence
@@ -234,68 +235,88 @@ def test_disabled_spans_insert_no_fences(monkeypatch):
     real = jax.block_until_ready
     monkeypatch.setattr(jax, "block_until_ready",
                         lambda v: (calls.append(1), real(v))[1])
+    opened = []
+    real_annotation = spans._TraceAnnotation
+
+    class CountingAnnotation(real_annotation):
+        def __init__(self, name, **kw):
+            opened.append(name)
+            super().__init__(name, **kw)
+
+    monkeypatch.setattr(spans, "_TraceAnnotation", CountingAnnotation)
+    assert not spans.capturing()
     convert_from_rows(convert_to_rows(t)[0], [INT32])
     murmur3_hash(t)
+    pmod(murmur3_hash(t), 7)
     assert calls == []
+    assert opened == []
 
     # and the SAME call sites do fence once recording is on
     obs.enable()
     try:
         convert_to_rows(t)
         assert len(calls) >= 1
+        assert opened == []   # still no capture: no annotation
     finally:
         obs.disable()
         obs.clear()
 
 
 # ---------------------------------------------------------------------------
-# metrics hardening (satellite: version-robust probe, fail-closed)
+# eager probe hardening (satellite: version-robust probe, fail-closed)
 # ---------------------------------------------------------------------------
 
-def test_metrics_probe_failure_fails_toward_not_recording(monkeypatch):
+def _span_calls(op):
+    fam = obs.metrics.registry().snapshot().get("srj_tpu_span_calls_total")
+    return (fam or {}).get("values", {}).get(f"op={op}", 0)
+
+
+def test_metrics_probe_failure_fails_toward_not_recording(monkeypatch,
+                                                          obs_on):
     def broken_probe():
         raise RuntimeError("probe exploded")
 
     monkeypatch.setattr(metrics, "_trace_probe", broken_probe)
-    metrics.reset()
-    metrics.enable()
-    try:
-        assert metrics.eager() is False
-        metrics.count("should_not_record")
-        assert metrics.snapshot() == {}
-    finally:
-        metrics.disable()
-        metrics.reset()
+    assert metrics.eager() is False
+    assert spans.recording() is False
+    with obs.span("should_not_record"):
+        pass
+    assert obs.events(kind="span") == []
+    assert _span_calls("should_not_record") == 0
 
 
-def test_metrics_probe_missing_fails_toward_not_recording(monkeypatch):
+def test_metrics_probe_missing_fails_toward_not_recording(monkeypatch,
+                                                          obs_on):
     monkeypatch.setattr(metrics, "_trace_probe", False)
-    metrics.reset()
-    metrics.enable()
-    try:
-        assert metrics.eager() is False
-        metrics.op("ghost", rows=10)
-        assert metrics.snapshot() == {}
-    finally:
-        metrics.disable()
-        metrics.reset()
+    assert metrics.eager() is False
+
+    @obs.span_fn(attrs=lambda n: {"rows": n})
+    def ghost(n):
+        return n
+
+    assert ghost(10) == 10
+    assert obs.events(kind="span") == []
+    assert _span_calls("ghost") == 0
 
 
 def test_metrics_enable_disable_race():
-    """Counter writers racing an enable/disable toggler must neither
-    raise nor corrupt the registry (the lock covers the counters; the
-    flag is a benign boolean read)."""
-    metrics.reset()
+    """Span writers racing an enable/disable toggler must neither raise
+    nor corrupt the registry or the ring (the lock covers both; the flag
+    is a benign boolean read)."""
+    obs.configure_sink(None)
+    obs.clear()
+    before = _span_calls("raced")
     stop = threading.Event()
 
     def toggler():
         while not stop.is_set():
-            metrics.enable()
-            metrics.disable()
+            obs.enable()
+            obs.disable()
 
     def writer():
-        for _ in range(2000):
-            metrics.count("raced")
+        for _ in range(500):
+            with obs.span("raced", rows=1):
+                pass
 
     tg = threading.Thread(target=toggler)
     ws = [threading.Thread(target=writer) for _ in range(4)]
@@ -306,8 +327,9 @@ def test_metrics_enable_disable_race():
         w.join()
     stop.set()
     tg.join()
-    snap = metrics.snapshot()
-    assert set(snap) <= {"raced"}
-    assert snap.get("raced", 0) <= 4 * 2000
-    metrics.disable()
-    metrics.reset()
+    obs.disable()
+    evs = obs.events(kind="span")
+    assert {e["name"] for e in evs} <= {"raced"}
+    recorded = _span_calls("raced") - before
+    assert recorded == len(evs) <= 4 * 500
+    obs.clear()

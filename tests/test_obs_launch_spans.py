@@ -1,0 +1,282 @@
+"""Program spans on the profiler's clock.
+
+Under a ``jax.profiler`` capture every span is an ``srj/<name>``
+annotation on the host plane, and each call that hands a program to the
+runtime on the hash and row paths sits in its own launch span whose
+``program`` attribute names the XLA module the call runs.  On the CPU
+each execution is an ``ExecuteHelper`` event on the host plane, run on
+the caller's thread inside the launch, and its ops carry the module name
+(``hlo_module``), which is what these tests read the captures against.
+"""
+
+import glob
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from spark_rapids_jni_tpu import (
+    BOOL8, Column, FLOAT64, INT8, INT16, INT32, INT64, Table, obs,
+)
+from spark_rapids_jni_tpu.obs import spans
+from spark_rapids_jni_tpu.ops import convert_to_rows, pallas_kernels
+from spark_rapids_jni_tpu.ops import row_conversion, row_mxu
+from spark_rapids_jni_tpu.ops.hashing import (
+    murmur3_hash, pmod, xxhash64, _murmur3_jit, _xx64_jit,
+)
+from spark_rapids_jni_tpu.ops.row_layout import compute_row_layout
+
+LAUNCHES = ("hash.prep", "hash.kernel", "hash.xla", "hash.seed",
+            "hash.finish", "rows.pack", "rows.encode", "rows.offsets")
+
+
+def _table(dtypes, n, seed=0):
+    rng = np.random.default_rng(seed)
+    return Table(tuple(
+        Column.from_numpy(rng.integers(-100, 100, n).astype(dt.np_dtype),
+                          dt, valid=rng.random(n) > 0.1)
+        for dt in dtypes))
+
+
+def _capture(tmp_path, fn):
+    """Run ``fn`` (warmed first, so nothing compiles in the capture)
+    under the profiler; return the ``srj/`` spans as ``(name, start,
+    end, metadata)`` and the program executions as ``(start, module)``."""
+    fn()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        jax.block_until_ready(fn())
+    finally:
+        jax.profiler.stop_trace()
+    from jax.profiler import ProfileData
+    [path] = glob.glob(os.path.join(str(tmp_path), "**", "*.xplane.pb"),
+                       recursive=True)
+    found, helpers, module_of = [], [], {}
+    for plane in ProfileData.from_file(path).planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for line in plane.lines:
+            for ev in line.events:
+                st = dict(ev.stats)
+                s, d = int(ev.start_ns), int(ev.duration_ns)
+                if ev.name.startswith(spans.TRACE_PREFIX):
+                    found.append((ev.name[len(spans.TRACE_PREFIX):], s,
+                                  s + d, st))
+                elif ev.name.endswith("ExecuteHelper"):
+                    helpers.append((s, st.get("run_id")))
+                elif "hlo_module" in st and "run_id" in st:
+                    module_of.setdefault(st["run_id"], st["hlo_module"])
+    found.sort(key=lambda e: e[1])
+    return found, sorted((s, module_of.get(r)) for s, r in helpers)
+
+
+def _named(found, name):
+    return [e for e in found if e[0] == name]
+
+
+def _check_launches(found, execs, entry):
+    """Inside each ``entry`` span every program execution lies in one
+    launch span, each launch span holds exactly one execution, and the
+    span's ``program`` is the module that ran."""
+    entries = _named(found, entry)
+    assert entries
+    launches = [e for e in found if e[0] in LAUNCHES]
+    for _, a, b, _ in entries:
+        inside = [(t, m) for t, m in execs if a <= t < b]
+        mine = [e for e in launches if a <= e[1] and e[2] <= b]
+        assert inside and len(mine) == len(inside)
+        for name, s, e, meta in mine:
+            ran = [m for t, m in inside if s <= t < e]
+            assert ran == [meta["program"]], (name, meta, ran)
+
+
+@pytest.fixture
+def pallas_on(monkeypatch):
+    monkeypatch.setenv("SRJ_TPU_PALLAS", "1")
+
+
+MIXED = [INT32, INT64, INT8, INT16, FLOAT64, BOOL8] * 6
+
+
+def test_murmur3_pallas_launch_spans(tmp_path, pallas_on):
+    t = _table(MIXED, 1024)
+    found, execs = _capture(tmp_path, lambda: murmur3_hash(t))
+    _check_launches(found, execs, "murmur3_hash")
+    [(_, a, b, meta)] = _named(found, "murmur3_hash")
+    assert meta["rows"] == 1024
+    assert all(a <= s and e <= b for n, s, e, _ in found if n in LAUNCHES)
+    preps = _named(found, "hash.prep")
+    assert [m["column"] for *_, m in preps] == list(range(len(MIXED)))
+    runs = pallas_kernels._hash_runs(t.columns, 0, "mm3")
+    kernels = _named(found, "hash.kernel")
+    assert len(runs) > 1
+    assert [m["run"] for *_, m in kernels] == list(range(len(runs)))
+    assert [m["words"] for *_, m in kernels] == [
+        pallas_kernels._desc_rows(pallas_kernels._hash_desc(r, 0, "mm3"))
+        for r in runs]
+    assert {m["program"] for *_, m in kernels} == {"jit__mm3_call"}
+    assert [n for n, *_ in found if n in ("hash.seed", "hash.finish")] == [
+        "hash.seed", "hash.finish"]
+
+
+def test_xxhash64_pallas_launch_spans(tmp_path, pallas_on):
+    t = _table([INT32, INT64, INT8], 256)
+    found, execs = _capture(tmp_path, lambda: xxhash64(t))
+    _check_launches(found, execs, "xxhash64")
+    assert len(_named(found, "hash.prep")) == 3
+    [(*_, meta)] = _named(found, "hash.kernel")
+    assert meta["program"] == "jit__xx64_call"
+    assert [m["program"] for n, *_, m in found
+            if n in ("hash.seed", "hash.finish")] == [
+        "jit__xx64_seed", "jit__xx64_finish"]
+
+
+def test_murmur3_xla_launch_span(tmp_path):
+    t = _table(MIXED, 1024)
+    found, execs = _capture(tmp_path, lambda: murmur3_hash(t))
+    _check_launches(found, execs, "murmur3_hash")
+    [(*_, meta)] = _named(found, "hash.xla")
+    assert meta["program"] == "jit__murmur3_jit"
+    assert not _named(found, "hash.prep")
+
+
+def test_pmod_entry_span(tmp_path):
+    h = murmur3_hash(_table([INT32], 64))
+    found, _ = _capture(tmp_path, lambda: pmod(h, 7))
+    [(*_, meta)] = _named(found, "pmod")
+    assert meta["rows"] == 64
+
+
+def test_grouped_rows_launch_spans(tmp_path):
+    dtypes = [INT32, INT64, INT8, INT16]
+    t = _table(dtypes, 2048)
+    layout = compute_row_layout(t.dtypes)
+    limit = 1024 * layout.fixed_row_size      # two batches of 1,024 rows
+
+    def call():
+        gc = row_mxu.table_to_grouped(t, layout)
+        return row_conversion.convert_to_rows_grouped(gc, size_limit=limit)
+
+    found, execs = _capture(tmp_path, call)
+    [(*_, pack)] = _named(found, "rows.pack")
+    assert pack["program"] == "jit__pack_grouped_jit"
+    _check_launches(found, execs, "convert_to_rows_grouped")
+    enc = _named(found, "rows.encode")
+    assert [(m["batch"], m["rows"]) for *_, m in enc] == [(0, 1024),
+                                                          (1, 1024)]
+    assert {m["program"] for *_, m in enc} == {"jit__grouped_encode_impl"}
+    offs = _named(found, "rows.offsets")
+    assert [(m["batch"], m["program"]) for *_, m in offs] == [
+        (b, p) for b in (0, 1) for p in ("jit__row_iota", "jit__row_scale")]
+
+
+@pytest.mark.parametrize("impl,program", [
+    ("xla", "jit__to_rows_fixed_jit"),
+    ("mxu", "jit__to_rows_mxu_jit"),
+    ("pallas", "jit__to_rows_planes_jit"),
+])
+def test_convert_to_rows_encode_span(tmp_path, impl, program):
+    t = _table([INT32, INT64, INT8, INT16], 256)
+    found, execs = _capture(tmp_path,
+                            lambda: convert_to_rows(t, impl=impl))
+    _check_launches(found, execs, "convert_to_rows")
+    [(*_, meta)] = _named(found, "rows.encode")
+    assert (meta["program"], meta["batch"], meta["rows"]) == (program, 0,
+                                                              256)
+
+
+def _module_name(jitted, *args):
+    return jitted.lower(*args).compiler_ir().operation.attributes[
+        "sym_name"].value
+
+
+def test_program_names_match_lowerings():
+    """``program`` is derived from the jitted callable; it must be the
+    name of the module the callable lowers to."""
+    t = _table([INT32, INT64], 256)
+    col = t.columns[0]
+    layout = compute_row_layout(t.dtypes)
+    h = jnp.zeros((1, 256), jnp.uint32)
+    gc = row_mxu.table_to_grouped(_table([INT32, INT64], 1024), layout)
+    encode = row_mxu._grouped_encode_fn(jax.devices()[0])
+    cases = [
+        (pallas_kernels._hash_prep, (col, 0, "mm3")),
+        (pallas_kernels._mm3_seed, (256, np.uint32(42))),
+        (pallas_kernels._mm3_finish, (h,)),
+        (pallas_kernels._xx64_seed, (256, np.uint32(42))),
+        (pallas_kernels._xx64_finish, (jnp.zeros((2, 256), jnp.uint32),)),
+        (_murmur3_jit, (t.columns, 42, 0)),
+        (_xx64_jit, (t.columns, 42, 0)),
+        (row_mxu._pack_grouped_jit, (t, layout)),
+        (row_conversion._row_iota, (1025,)),
+        (row_conversion._row_scale, (jnp.arange(1025, dtype=jnp.int32),
+                                     layout.fixed_row_size)),
+        (row_conversion._to_rows_fixed_jit, (t, layout, jnp.int32(0),
+                                             None)),
+    ]
+    for fn, args in cases:
+        assert _module_name(fn, *args) == spans.program_name(fn)
+    assert _module_name(encode, gc.planes, gc.vmask, layout, 1024, True,
+                        0) == spans.program_name(
+        row_mxu._grouped_encode_impl)
+
+
+def test_recorded_and_captured_span(tmp_path):
+    """With obs on under a capture a span is both a ring event and an
+    annotation, and attributes set late reach the annotation."""
+    obs.clear()
+    obs.enable()
+    try:
+        def call():
+            with obs.span("both", rows=3) as sp:
+                sp.set(batch=1)
+            return jnp.zeros(1)
+        found, _ = _capture(tmp_path, call)
+        evs = [e for e in obs.events(kind="span") if e["name"] == "both"]
+    finally:
+        obs.disable()
+        obs.clear()
+    assert evs and evs[-1]["rows"] == 3 and evs[-1]["batch"] == 1
+    [(*_, meta)] = _named(found, "both")
+    assert meta == {"rows": 3, "batch": 1}
+
+
+def test_recorded_hash_call_reads_memory_once(monkeypatch, pallas_on):
+    """Only the entry span reads the allocator and resets its peak: the
+    launch spans inside a recorded hash call read no memory."""
+    reads, resets = [], []
+    monkeypatch.setattr(spans, "_mem_snapshot",
+                        lambda: (reads.append(1), {"bytes_in_use": 1,
+                                                   "peak_bytes_in_use": 2})[1])
+    monkeypatch.setattr(spans, "_reset_peak", lambda: resets.append(1))
+    t = _table(MIXED, 256)
+    murmur3_hash(t)
+    obs.clear()
+    obs.enable()
+    try:
+        murmur3_hash(t)
+        evs = obs.events(kind="span")
+    finally:
+        obs.disable()
+        obs.clear()
+    assert len(reads) == 2 and len(resets) == 1   # entry start and end
+    launches = [e for e in evs if e["name"] in LAUNCHES]
+    assert len(launches) > len(MIXED)
+    assert not any("mem" in e for e in launches)
+    [entry] = [e for e in evs if e["name"] == "murmur3_hash"]
+    assert entry["mem"]["peak_delta_bytes"] == 1
+
+
+def test_no_capture_opens_no_annotation(monkeypatch):
+    assert not spans.capturing()
+    opened = []
+    monkeypatch.setattr(spans, "_TraceAnnotation",
+                        lambda *a, **k: opened.append(a))
+    with spans.launch("hash.prep", pallas_kernels._hash_prep) as sp:
+        sp.set(column=1)
+        assert sp.fence(5) == 5
+    with obs.span("x"):
+        pass
+    assert opened == []

@@ -77,27 +77,44 @@ def test_build_info_has_core_keys():
 
 
 def test_metrics_registry_counts_operators():
+    """Operator calls, rows and bytes land in the obs registry's span
+    families while spans record, and nothing lands there while off."""
     import numpy as np
-    from spark_rapids_jni_tpu import Column, Table, INT32
+    from spark_rapids_jni_tpu import Column, Table, INT32, obs
     from spark_rapids_jni_tpu.ops import convert_to_rows, convert_from_rows
     from spark_rapids_jni_tpu.ops.hashing import murmur3_hash
-    from spark_rapids_jni_tpu.utils import metrics
-    metrics.reset()
-    metrics.enable()
+
+    def counts():
+        snap = obs.metrics.registry().snapshot()
+        return {fam: dict(snap.get(fam, {}).get("values", {}))
+                for fam in ("srj_tpu_span_calls_total",
+                            "srj_tpu_span_rows_total",
+                            "srj_tpu_span_bytes_total")}
+
+    def delta(a, b, fam, op):
+        key = f"op={op}"
+        return b[fam].get(key, 0) - a[fam].get(key, 0)
+
+    before = counts()
+    obs.enable()
     try:
         t = Table((Column.from_numpy(np.arange(64, dtype=np.int32), INT32),))
         [rows] = convert_to_rows(t)
         convert_from_rows(rows, t.dtypes)
         murmur3_hash(t)
-        snap = metrics.snapshot()
-        assert snap["convert_to_rows.calls"] == 1
-        assert snap["convert_to_rows.rows"] == 64
-        assert snap["convert_from_rows.bytes"] == int(np.asarray(rows.offsets)[-1])
-        assert snap["murmur3_hash.rows"] == 64
     finally:
-        metrics.disable()
-        metrics.reset()
-    # disabled: zero overhead path records nothing
+        obs.disable()
+        obs.clear()
+    after = counts()
+    assert delta(before, after, "srj_tpu_span_calls_total",
+                 "convert_to_rows") == 1
+    assert delta(before, after, "srj_tpu_span_rows_total",
+                 "convert_to_rows") == 64
+    assert delta(before, after, "srj_tpu_span_bytes_total",
+                 "convert_from_rows") == int(np.asarray(rows.offsets)[-1])
+    assert delta(before, after, "srj_tpu_span_rows_total",
+                 "murmur3_hash") == 64
+    # disabled: the zero-overhead path records nothing
     murmur3_hash(Table((Column.from_numpy(np.arange(4, dtype=np.int32),
                                           INT32),)))
-    assert metrics.snapshot() == {}
+    assert counts() == after
