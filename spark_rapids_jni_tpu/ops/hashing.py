@@ -84,6 +84,32 @@ def _mm3_fmix(h1, length):
     return h1 ^ (h1 >> 16)
 
 
+def _canonical_floats(data):
+    """-0.0 -> 0.0 and NaN -> canonical quiet NaN, as Java's
+    floatToIntBits/doubleToLongBits produce for Spark."""
+    data = jnp.where(data == 0.0, jnp.zeros_like(data), data)
+    return jnp.where(jnp.isnan(data), jnp.full_like(data, jnp.nan), data)
+
+
+def _canonical_f32_bits(data):
+    """A float32 array's Spark-normalized bits as uint32."""
+    return jax.lax.bitcast_convert_type(_canonical_floats(data), jnp.uint32)
+
+
+def _canonical_f64_words(lo, hi):
+    """A plane-pair double's Spark-normalized ``[lo, hi]`` words:
+    -0.0 and NaN normalized at the bit level, so TPU (no-x64) hashes
+    agree with the x64/Spark path."""
+    exp_all_ones = (hi & jnp.uint32(0x7FF00000)) == jnp.uint32(0x7FF00000)
+    mant_nonzero = ((hi & jnp.uint32(0x000FFFFF)) | lo) != 0
+    is_nan = exp_all_ones & mant_nonzero
+    is_negzero = (hi == jnp.uint32(0x80000000)) & (lo == 0)
+    hi = jnp.where(is_nan, jnp.uint32(0x7FF80000),
+                   jnp.where(is_negzero, jnp.uint32(0), hi))
+    lo = jnp.where(is_nan | is_negzero, jnp.uint32(0), lo)
+    return [lo, hi]
+
+
 def _as_u32_words(col: Column):
     """A column's Spark-normalized little-endian uint32 words as a LIST
     of [n] vectors (lo word first).
@@ -105,23 +131,10 @@ def _as_u32_words(col: Column):
     k = dt.np_dtype.itemsize
     if dt.np_dtype.kind == "f":
         if k == 8 and data.ndim == 2:
-            # plane-pair double: normalize -0.0 and NaN at the bit level
-            # so TPU (no-x64) hashes agree with the x64/Spark path
-            lo, hi = data[0], data[1]
-            exp_all_ones = (hi & jnp.uint32(0x7FF00000)) == jnp.uint32(0x7FF00000)
-            mant_nonzero = ((hi & jnp.uint32(0x000FFFFF)) | lo) != 0
-            is_nan = exp_all_ones & mant_nonzero
-            is_negzero = (hi == jnp.uint32(0x80000000)) & (lo == 0)
-            hi = jnp.where(is_nan, jnp.uint32(0x7FF80000),
-                           jnp.where(is_negzero, jnp.uint32(0), hi))
-            lo = jnp.where(is_nan | is_negzero, jnp.uint32(0), lo)
-            return [lo, hi]
-        # -0.0 -> 0.0 and NaN -> canonical quiet NaN, as Java's
-        # floatToIntBits/doubleToLongBits produce for Spark
-        data = jnp.where(data == 0.0, jnp.zeros_like(data), data)
-        data = jnp.where(jnp.isnan(data), jnp.full_like(data, jnp.nan), data)
+            return _canonical_f64_words(data[0], data[1])
         if k == 4:
-            return [jax.lax.bitcast_convert_type(data, jnp.uint32)]
+            return [_canonical_f32_bits(data)]
+        data = _canonical_floats(data)
         pair = jax.lax.bitcast_convert_type(
             jax.lax.bitcast_convert_type(data, jnp.uint64).reshape(-1, 1),
             jnp.uint32).reshape(-1, 2)
@@ -355,7 +368,7 @@ def _dispatch_hash(op: str, pcols, seed: int, Wb: int, xla_jit):
     bucketed hash call (``SRJ_TPU_PALLAS`` knob, ``runtime/shapes``
     bucket already applied).  Pallas covers fixed-width non-nested
     columns plus dense-padded string columns (the bucketed char window
-    ``Wb`` rides the stacked word matrix); Arrow-layout or width-capped
+    ``Wb`` sets their word rows); Arrow-layout or width-capped
     strings and decimal128 stay on the XLA chain via ``choose()``'s
     per-op eligibility hook, which stamps ``impl=xla,
     reason=ineligible``.  Either way the span is stamped with ``impl=``

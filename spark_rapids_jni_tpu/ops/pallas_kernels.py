@@ -12,11 +12,17 @@ kernels over HBM-resident columns* instead of generic XLA lowerings:
   rejects / lane-pads 32x), and emits the tile TRANSPOSED as word planes
   ``[W, tile]``.  Plane-major output means 64-bit plane-pair columns and
   the packed validity masks need no further transposes.
-- :func:`murmur3_fixed` / :func:`xxhash64_fixed` — the Spark hash chains
-  over column tiles.  The Spark-normalized uint32 word matrix is built
-  once outside the kernel (pure bitcasts/slices); the kernel replays the
-  *same* mix/fmix helper chain from :mod:`ops.hashing` over each VMEM
-  tile, so bit-exactness with the XLA lowering is by construction.
+- :func:`murmur3_cols` — the Spark murmur3 chain reading each column's
+  own buffer and packed validity through bitcast-only views; Spark's
+  normalization and the null skip run in VMEM on a dense ``[64, 128]``
+  hash state, and the first and last kernel calls seed and finish the
+  hash themselves.  Dense-padded string columns keep one prep program
+  that lays the char window out as word rows.
+- :func:`xxhash64_cols` — the xxhash64 chain over column tiles.  The
+  Spark-normalized uint32 word matrix is built outside the kernel
+  (pure bitcasts/slices); the kernel replays the *same* mix/fmix helper
+  chain from :mod:`ops.hashing` over each VMEM tile, so bit-exactness
+  with the XLA lowering is by construction.
 - :func:`bloom_might_contain` — bloom probe FUSED with its two hashLong
   evaluations; the bitset rides a constant-index BlockSpec so it stays
   VMEM-resident across every row tile instead of paying k random HBM
@@ -36,12 +42,11 @@ kernels over HBM-resident columns* instead of generic XLA lowerings:
   positions, replacing the ``lax.scan`` step chain for bucketed
   fixed-max-len inputs.  Emits only the fields the extraction tail
   consumes (start/end/found/capturing/bad/deep).
-- :func:`murmur3_cols` / :func:`xxhash64_cols` — the hash chains grown
-  to STRING columns: a padded char window rides the stacked word
-  matrix as ``Wp//4`` extra word rows plus one length row per string
-  column, and the in-kernel tail-block masking replays
-  ``hashing._mm3_string_col`` / ``_xx64_string_col`` word-for-word
-  (tail bytes come from a select-captured word, never a gather).
+- String columns in either hash: a padded char window rides as
+  ``Wp//4`` word rows plus one length row per string column, and the
+  in-kernel tail-block masking replays ``hashing._mm3_string_col`` /
+  ``_xx64_string_col`` word-for-word (tail bytes come from a
+  select-captured word, never a gather).
 
 Selection is per ``(op, sig, bucket)`` behind ``SRJ_TPU_PALLAS``:
 ``1`` = Pallas everywhere it is supported (interpret-mode off-TPU),
@@ -453,6 +458,20 @@ def hash_cols_eligible(cols) -> bool:
     return True
 
 
+#: widest char window the murmur3 kernel takes: a column that wide
+#: runs alone, and its blocks of one chunk, double-buffered, take 67 MB
+#: of a v5e's 128 MiB of VMEM at 4 KiB (the compile takes 17 s)
+_MM3_MAX_WINDOW = 1 << 12
+
+
+def _mm3_cols_eligible(cols) -> bool:
+    """The murmur3 hook: :func:`hash_cols_eligible`, with string
+    windows of at most :data:`_MM3_MAX_WINDOW` bytes."""
+    return hash_cols_eligible(cols) and all(
+        c.chars2d.shape[1] <= _MM3_MAX_WINDOW
+        for c in cols if c.dtype.is_string)
+
+
 def _string_window(W: int, mode: str) -> int:
     # the bucketed char window block-aligned to the op's stride
     # (murmur3: 4-byte blocks, xxhash64: 8-byte stripes)
@@ -461,14 +480,12 @@ def _string_window(W: int, mode: str) -> int:
 
 def _hash_desc(cols, W: int, mode: str) -> tuple:
     """The static per-column descriptor of :func:`_hash_mats`, from the
-    dtypes alone: ``("f", nwords)`` for a fixed column, ``("s",
-    Wp//4)`` for a string column."""
+    dtypes alone: ``("f", 2)`` for a fixed column, ``("s", Wp//4)`` for
+    a string column."""
     desc = []
     for c in cols:
         if c.dtype.is_string:
             desc.append(("s", _string_window(W, mode) // 4))
-        elif mode == "mm3":
-            desc.append(("f", 2 if c.dtype.itemsize == 8 else 1))
         else:
             desc.append(("f", 2))
     return tuple(desc)
@@ -484,13 +501,13 @@ def _hash_mats(cols, W: int, mode: str):
     per-column descriptor (:func:`_hash_desc`), plus the validity
     matrix [C, n] uint8.
 
-    Fixed columns contribute their Spark-normalized words — desc
-    ``("f", nwords)`` (murmur3), or the (hi, lo) 8-byte block pair —
-    desc ``("f", 2)`` (xxhash64).  String columns contribute the padded
-    char window as ``Wp//4`` little-endian word rows plus ONE length
-    row — desc ``("s", Wp//4)`` — where ``Wp`` block-aligns the
-    bucketed window ``W`` to the op's stride (murmur3: 4-byte blocks,
-    xxhash64: 8-byte stripes), exactly as the XLA string paths do."""
+    The xxhash64 kernel's operands (murmur3 reads the columns as
+    stored, see :func:`murmur3_cols`).  Fixed columns contribute their
+    Spark-normalized (hi, lo) 8-byte block pair — desc ``("f", 2)``.
+    String columns contribute the padded char window as ``Wp//4``
+    little-endian word rows plus ONE length row — desc ``("s", Wp//4)``
+    — where ``Wp`` aligns the bucketed window ``W`` to 8-byte stripes,
+    exactly as the XLA string path does."""
     from spark_rapids_jni_tpu.ops import hashing as H
     from spark_rapids_jni_tpu.table import bytes2d_to_words
     n = cols[0].num_rows
@@ -501,8 +518,6 @@ def _hash_mats(cols, W: int, mode: str):
             if Wp:
                 mats.append(bytes2d_to_words(c.chars_window(Wp)).T)
             mats.append(c.str_lens().astype(jnp.uint32)[None, :])
-        elif mode == "mm3":
-            mats.append(jnp.stack(H._as_u32_words(c)))
         else:
             mats.append(jnp.stack(H._col_u64_blocks(c)))
     wmat = (jnp.concatenate(mats, axis=0) if mats
@@ -581,57 +596,6 @@ def _mm3_string_lanes(h, wrows, lens):
     return H._mm3_fmix(hc, lens)
 
 
-def _mm3_kernel(desc, w_ref, v_ref, h_ref, o_ref):
-    from spark_rapids_jni_tpu.ops import hashing as H
-    w = w_ref[...]
-    v = v_ref[...]
-    h = h_ref[0, :]
-    k = 0
-    for ci, (kind, nw) in enumerate(desc):
-        if kind == "s":
-            lens = w[k + nw].astype(jnp.int32)
-            hc = _mm3_string_lanes(h, w[k:k + nw], lens)
-            k += nw + 1
-        else:
-            hc = h
-            for j in range(nw):
-                hc = H._mm3_mix_h1(hc, w[k + j])
-            hc = H._mm3_fmix(hc, nw * 4)
-            k += nw
-        h = jnp.where(v[ci] != 0, hc, h)
-    o_ref[...] = h[None, :]
-
-
-@functools.partial(jax.jit, static_argnums=(2, 3))
-def _mm3_call(pieces, h, desc, interpret: bool) -> jnp.ndarray:
-    """One kernel call over a run's per-column word and validity rows
-    (``_hash_prep``), chaining from the running hash ``h`` ([1, n]
-    uint32).  Keyed on ``desc``, not on the columns'
-    dtypes: runs with the same word layout share one compiled kernel."""
-    wmat = jnp.concatenate([w for w, _ in pieces])
-    vmat = jnp.concatenate([v for _, v in pieces])
-    K, C, n = wmat.shape[0], vmat.shape[0], h.shape[1]
-    tile = _hash_tile(K + C + 2)
-    npad = max(tile, -(-n // tile) * tile)
-    out = pl.pallas_call(
-        functools.partial(_mm3_kernel, desc),
-        grid=(npad // tile,),
-        in_specs=[
-            pl.BlockSpec((K, tile), lambda r: (0, r),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((C, tile), lambda r: (0, r),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, tile), lambda r: (0, r),
-                         memory_space=pltpu.VMEM),
-        ],
-        out_specs=pl.BlockSpec((1, tile), lambda r: (0, r),
-                               memory_space=pltpu.VMEM),
-        out_shape=jax.ShapeDtypeStruct((1, npad), jnp.uint32),
-        interpret=interpret,
-    )(_pad_lanes(wmat, npad), _pad_lanes(vmat, npad), _pad_lanes(h, npad))
-    return out[:, :n]
-
-
 def _chain_calls(call, mode: str, cols, h, W: int, interpret: bool):
     """Run a hash kernel over ``cols`` in :func:`_hash_runs`, each call
     chaining from the last one's running hash ``h``."""
@@ -649,30 +613,312 @@ def _chain_calls(call, mode: str, cols, h, W: int, interpret: bool):
     return h
 
 
-@functools.partial(jax.jit, static_argnums=(0,))
-def _mm3_seed(n: int, seed) -> jnp.ndarray:
-    return jnp.full((1, n), seed, jnp.uint32)
+# -- murmur3 over the resident column buffers --------------------------------
+#
+# The kernel reads each fixed-width column's own data buffer and packed
+# validity, viewed by bitcast-only reshapes: a 1-, 2- or 4-byte column
+# as ``[n/128, 128]``, a 64-bit column's ``[2, n]`` lo/hi planes as
+# ``(2, rows)`` blocks, its validity bytes as ``[n/1024, 128]``.  Spark's
+# normalization (sign or zero extension, float canonicalization) and the
+# null skip run in VMEM on a dense ``[_MM3_CHUNK, 128]`` hash state, so
+# every uint32 op fills whole vregs.  A dense-padded string column keeps
+# one prep program, which lays its char window out as word rows in the
+# same dense form.
+
+#: sublane rows of the running hash in one step of the kernel's chunk
+#: loop: 8 vregs of uint32, whose validity is 8 full rows of 128 bytes
+_MM3_CHUNK = 64
+
+#: table rows one chunk covers; the padded row count is a multiple
+_MM3_ROWS = _MM3_CHUNK * _LANES
+
+#: most sublane rows one grid step moves: 64K table rows
+_MM3_MAX_BLOCK = 512
+
+#: VMEM for one kernel call's double-buffered blocks: the column
+#: blocks of a grid step, twice, stay within it
+_MM3_VMEM = 8 << 20
+
+#: columns per murmur3 kernel call.  The chunk body is unrolled over
+#: the run's columns, so the compile grows with them, and a wider run
+#: takes smaller blocks.  Over the 212-column reference table at 4M
+#: rows on a v5e, runs of 9, 27, 54 and 212 columns took 7.5, 6.8, 8.2
+#: and 10.1 ms a call and compiled in 0.8, 2.1, 4.0 and 6.3 s
+_MM3_COLS = 27
+
+#: columns per call in interpret mode: XLA:CPU fuses a run's whole
+#: chain into one loop, and with the loop emitters (the fusion emitters
+#: off, as the test suite runs) its compile grows exponentially with the
+#: columns: 8 compile in 2 s, 12 in 50 s
+_MM3_INTERPRET_COLS = 8
 
 
-@jax.jit
-def _mm3_finish(h) -> jnp.ndarray:
-    return jax.lax.bitcast_convert_type(h[0], jnp.int32)
+def _mm3_rows(n: int) -> int:
+    """Rows the kernel runs over: ``n`` padded to whole chunks."""
+    return max(1, -(-n // _MM3_ROWS)) * _MM3_ROWS
+
+
+#: storage dtypes narrower than 4 bytes, by how Spark widens them
+_MM3_NARROW = {np.dtype(np.int8): "i8", np.dtype(np.int16): "i16",
+               np.dtype(np.uint8): "u8", np.dtype(np.uint16): "u16",
+               np.dtype(np.bool_): "u8"}
+
+
+def _mm3_kind(col, Wp: int) -> tuple:
+    """How the kernel reads one column: ``(kind, nwords, has_validity)``.
+    ``kind`` is the storage form that decides the Spark normalization:
+    ``i8``/``i16`` sign-extend, ``u8``/``u16`` zero-extend, ``w32`` is
+    taken as it is, ``f32`` canonicalized, ``w64``/``f64`` the two
+    words of a 64-bit value (raw or canonicalized), ``str`` a
+    dense-padded string's ``Wp // 4`` word rows and length row."""
+    valid = col.validity is not None
+    dt = col.dtype
+    if dt.is_string:
+        return ("str", Wp // 4, valid)
+    is_float = dt.kind.startswith("float")
+    if dt.itemsize == 8:
+        return ("f64" if is_float else "w64", 2, valid)
+    if is_float:
+        return ("f32", 1, valid)
+    return (_MM3_NARROW.get(col.data.dtype, "w32"), 1, valid)
+
+
+_MM3_ITEM_BYTES = {"i8": 1, "u8": 1, "i16": 2, "u16": 2, "w32": 4,
+                   "f32": 4, "w64": 8, "f64": 8}
+
+
+def _mm3_row_bytes(kind: tuple) -> float:
+    # bytes a table row of this column brings into VMEM
+    k, nw, valid = kind
+    size = 4 * (nw + 1) if k == "str" else _MM3_ITEM_BYTES[k]
+    return size + valid / 8
+
+
+def _mm3_vmem(width: float, S: int) -> float:
+    # the double-buffered blocks of a grid step of S sublane rows, for
+    # columns of ``width`` bytes a row plus the hash in and out
+    return 2 * S * _LANES * (width + 8)
+
+
+def _mm3_runs(desc, interpret: bool = False) -> List[Tuple[int, int]]:
+    """``[start, end)`` column ranges of the kernel calls, in chain
+    order: at most :data:`_MM3_COLS` columns (:data:`_MM3_INTERPRET_COLS`
+    in interpret mode), and blocks of one chunk that fit
+    :data:`_MM3_VMEM` (a wider column runs alone)."""
+    cap = _MM3_INTERPRET_COLS if interpret else _MM3_COLS
+    runs, start, width = [], 0, 0.0
+    for i, kind in enumerate(desc):
+        w = _mm3_row_bytes(kind)
+        if i > start and (i - start == cap or
+                          _mm3_vmem(width + w, _MM3_CHUNK) > _MM3_VMEM):
+            runs.append((start, i))
+            start, width = i, 0.0
+        width += w
+    runs.append((start, len(desc)))
+    return runs
+
+
+def _mm3_block(desc, npad: int) -> int:
+    """Sublane rows per grid step: the most chunks whose blocks fit
+    :data:`_MM3_VMEM`, up to :data:`_MM3_MAX_BLOCK`, that divide the
+    padded rows."""
+    width = sum(_mm3_row_bytes(k) for k in desc)
+    S = _MM3_CHUNK
+    while (2 * S <= _MM3_MAX_BLOCK and (npad // _LANES) % (2 * S) == 0
+           and _mm3_vmem(width, 2 * S) <= _MM3_VMEM):
+        S *= 2
+    return S
+
+
+class _RefRows:
+    """Rows ``[j, r0:r0+rows, :]`` of a ``[nw, S, 128]`` block, loaded on
+    first use: ``_mm3_string_lanes`` indexes its word rows one by one."""
+
+    def __init__(self, ref, r0, rows: int, nw: int):
+        self.ref, self.r0, self.rows, self.shape = ref, r0, rows, (nw,)
+
+    def __getitem__(self, j):
+        return self.ref[j, pl.ds(self.r0, self.rows), :]
+
+
+def _mm3_column(kind: tuple, d_ref, r0, h):
+    """One column's Spark murmur3 step over the chunk at sublane row
+    ``r0``, as ``hashing._murmur3_chain`` takes it (words from
+    ``hashing._as_u32_words``)."""
+    from spark_rapids_jni_tpu.ops import hashing as H
+    k, nw, _ = kind
+    rows = _MM3_CHUNK
+    if k == "str":
+        lens = d_ref[nw, pl.ds(r0, rows), :].astype(jnp.int32)
+        return _mm3_string_lanes(h, _RefRows(d_ref, r0, rows, nw), lens)
+    if k in ("w64", "f64"):
+        span = pl.ds(r0 * _LANES, rows * _LANES)
+        words = [d_ref[j, span].reshape(rows, _LANES) for j in (0, 1)]
+        if k == "f64":
+            words = H._canonical_f64_words(*words)
+    else:
+        x = d_ref[pl.ds(r0, rows), :]
+        if k == "f32":
+            x = H._canonical_f32_bits(x)
+        elif k in ("i8", "i16"):
+            x = jax.lax.bitcast_convert_type(x.astype(jnp.int32),
+                                             jnp.uint32)
+        elif k in ("u8", "u16"):
+            x = x.astype(jnp.uint32)
+        elif x.dtype != jnp.uint32:
+            x = jax.lax.bitcast_convert_type(x, jnp.uint32)
+        words = [x]
+    for w in words:
+        h = H._mm3_mix_h1(h, w)
+    return H._mm3_fmix(h, 4 * len(words))
+
+
+def _mm3_dense_kernel(desc, seed: int, first: bool, last: bool, *refs):
+    """The chain over one run of columns for one grid step of ``S``
+    sublane rows, a :data:`_MM3_CHUNK`-row chunk at a time.  Validity
+    bytes cover 8 sublane rows each: byte ``16*(r%8) + l//8`` of the
+    chunk's ``r//8``-th validity row holds row ``(r, l)``'s bit ``l%8``."""
+    refs = list(refs)
+    vs_ref, o_ref = refs.pop(), refs.pop()
+    h_ref = None if first else refs.pop(0)
+    it = iter(refs)
+    cols, nv = [], 0
+    for kind in desc:
+        d_ref = next(it)
+        if kind[2]:
+            # widened once a step, so the chunk loop slices 32-bit tiles
+            vs_ref[nv] = next(it)[...].astype(jnp.int32)
+            cols.append((kind, d_ref, nv))
+            nv += 1
+        else:
+            cols.append((kind, d_ref, None))
+    rows, vrows = _MM3_CHUNK, _MM3_CHUNK // 8
+    sub = jax.lax.broadcasted_iota(jnp.int32, (rows, _LANES), 0)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (rows, _LANES), 1)
+    byte_at = 16 * (sub % 8) + lane // 8
+    bit_at = lane % 8
+
+    def chunk(i, carry):
+        r0 = pl.multiple_of(i * rows, rows)
+        h = (jnp.full((rows, _LANES), seed, jnp.uint32) if first
+             else h_ref[pl.ds(r0, rows), :])
+        for kind, d_ref, j in cols:
+            hc = _mm3_column(kind, d_ref, r0, h)
+            if j is None:
+                h = hc
+            else:
+                v = vs_ref[j, pl.ds(pl.multiple_of(i * vrows, vrows),
+                                    vrows), :]
+                v = jnp.broadcast_to(v[:, None, :], (vrows, 8, _LANES))
+                v = jnp.take_along_axis(v.reshape(rows, _LANES), byte_at,
+                                        axis=1)
+                h = jnp.where(((v >> bit_at) & 1) != 0, hc, h)
+        o_ref[pl.ds(r0, rows), :] = (
+            jax.lax.bitcast_convert_type(h, jnp.int32) if last else h)
+        return carry
+
+    jax.lax.fori_loop(0, o_ref.shape[0] // rows, chunk, 0)
+
+
+@functools.partial(jax.jit, static_argnums=(2, 3, 4, 5, 6))
+def _mm3_run(cols, h, desc, seed: int, n: int, last: bool,
+             interpret: bool):
+    """One kernel call over a run of columns, ``(data, validity)`` each
+    (a string column's data is its :func:`_mm3_string_prep` block).
+    The first run (``h`` None) seeds the hash itself; the others chain
+    from ``h`` ``[npad/128, 128]``.  The last returns the int32 ``[n]``
+    hash.  Nothing but bitcasts lies between a column buffer whose rows
+    fill whole chunks and the kernel."""
+    npad = _mm3_rows(n)
+    S = _mm3_block(desc, npad)
+    # a column too wide for the budget runs alone, with the VMEM its
+    # blocks take
+    need = int(_mm3_vmem(sum(_mm3_row_bytes(k) for k in desc), S))
+    vmem = None if need <= _MM3_VMEM else need + (8 << 20)
+    ops, specs = [], []
+
+    def add(x, block, index):
+        ops.append(x)
+        specs.append(pl.BlockSpec(block, index, memory_space=pltpu.VMEM))
+
+    if h is not None:
+        add(h, (S, _LANES), lambda r: (r, 0))
+    for (data, validity), (kind, nw, valid) in zip(cols, desc):
+        if kind == "str":
+            add(data, (nw + 1, S, _LANES), lambda r: (0, r, 0))
+        elif kind in ("w64", "f64"):
+            if data.ndim == 1:             # native 64-bit under x64
+                data = jax.lax.bitcast_convert_type(data, jnp.uint32).T
+            add(_pad_lanes(data, npad), (2, S * _LANES), lambda r: (0, r))
+        else:
+            if data.dtype == jnp.bool_:
+                data = data.astype(jnp.uint8)
+            add(_pad_rows(data, npad).reshape(-1, _LANES), (S, _LANES),
+                lambda r: (r, 0))
+        if valid:
+            add(_pad_rows(validity, npad // 8).reshape(-1, _LANES),
+                (S // 8, _LANES), lambda r: (r, 0))
+    nv = max(1, sum(k[2] for k in desc))
+    out = pl.pallas_call(
+        functools.partial(_mm3_dense_kernel, desc, seed, h is None, last),
+        grid=(npad // (S * _LANES),),
+        in_specs=specs,
+        out_specs=pl.BlockSpec((S, _LANES), lambda r: (r, 0),
+                               memory_space=pltpu.VMEM),
+        out_shape=jax.ShapeDtypeStruct(
+            (npad // _LANES, _LANES), jnp.int32 if last else jnp.uint32),
+        scratch_shapes=[pltpu.VMEM((nv, S // 8, _LANES), jnp.int32)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel",), vmem_limit_bytes=vmem),
+        interpret=interpret,
+    )(*ops)
+    return out.reshape(npad)[:n] if last else out
+
+
+@functools.partial(jax.jit, static_argnums=(1, 2))
+def _mm3_string_prep(col, Wp: int, npad: int):
+    """A dense-padded string column as the kernel reads it: its
+    ``Wp // 4`` little-endian word rows and its length row, each laid
+    out ``[npad/128, 128]``."""
+    from spark_rapids_jni_tpu.table import bytes2d_to_words
+    rows = [col.str_lens().astype(jnp.uint32)[None, :]]
+    if Wp:
+        rows.insert(0, bytes2d_to_words(col.chars_window(Wp)).T)
+    words = _pad_lanes(jnp.concatenate(rows), npad)
+    return words.reshape(words.shape[0], npad // _LANES, _LANES)
 
 
 def murmur3_cols(cols, seed: int, *, W: int = 0,
                  interpret: bool = False) -> jnp.ndarray:
     """Spark murmur3 chain over fixed-width AND dense-padded string
-    columns, one VMEM tile of rows per grid step.  ``W`` is the
-    bucketed char-window width shared by all string columns (0 when
-    none).  Bit-exact with ``hashing._murmur3_chain``.  The seed row
-    and the final bitcast are one program each, so each launch is one
-    ``launch`` span."""
+    columns: int32 ``[n]``.  ``W`` is the bucketed char-window width
+    shared by all string columns (0 when none).  Bit-exact with
+    ``hashing._murmur3_chain``.  Each program launch is one ``launch``
+    span: a ``hash.prep`` per string column, a ``hash.kernel`` per run
+    of columns (``native_cols`` read from their own buffers,
+    ``prep_cols`` through a prep)."""
     cols = tuple(cols)
-    with spans.launch("hash.seed", _mm3_seed):
-        h = _mm3_seed(cols[0].num_rows, np.uint32(seed))
-    h = _chain_calls(_mm3_call, "mm3", cols, h, int(W), interpret)
-    with spans.launch("hash.finish", _mm3_finish):
-        return _mm3_finish(h)
+    n = cols[0].num_rows
+    npad = _mm3_rows(n)
+    Wp = _string_window(int(W), "mm3")
+    desc = tuple(_mm3_kind(c, Wp) for c in cols)
+    ops = []
+    for i, (c, kind) in enumerate(zip(cols, desc)):
+        data = c.data
+        if kind[0] == "str":
+            with spans.launch("hash.prep", _mm3_string_prep, column=i):
+                data = _mm3_string_prep(c, Wp, npad)
+        ops.append((data, c.validity))
+    seed = int(seed) & 0xFFFFFFFF
+    runs = _mm3_runs(desc, interpret)
+    h = None
+    for r, (a, b) in enumerate(runs):
+        prep = sum(k[0] == "str" for k in desc[a:b])
+        with spans.launch("hash.kernel", _mm3_run, run=r,
+                          native_cols=b - a - prep, prep_cols=prep):
+            h = _mm3_run(tuple(ops[a:b]), h, desc[a:b], seed, n,
+                         r == len(runs) - 1, interpret)
+    return h
 
 
 #: historical fixed-only entry point; the generalized kernel accepts
@@ -1039,7 +1285,7 @@ def _bloom_eligible(sig, platform) -> bool:
 #: here are always eligible.  Hash-op sigs are the column tuples
 #: themselves, the rest are static shape tuples — see each predicate.
 _ELIGIBLE = {
-    "murmur3_hash": _shape_hook(hash_cols_eligible),
+    "murmur3_hash": _shape_hook(_mm3_cols_eligible),
     "xxhash64": _shape_hook(hash_cols_eligible),
     "get_json_object": _shape_hook(_gjo_sig_eligible),
     "convert_to_rows": _shape_hook(_rows_sig_eligible),

@@ -14,6 +14,8 @@ only one process may load libtpu.
 """
 
 
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -124,12 +126,44 @@ def test_word_plane_row_kernels(one_chip, direction):
 
 @pytest.mark.parametrize("kernel", ["murmur3_cols", "xxhash64_cols"])
 def test_hash_kernel(one_chip, kernel):
-    """Spark murmur3 / xxhash64 over 14 columns of the table: one or
-    two kernel calls of at most ``pallas_kernels._HASH_WORDS`` word
-    rows."""
+    """Spark murmur3 / xxhash64 over 14 columns of the table: one
+    murmur3 kernel call; xxhash64 calls of at most
+    ``pallas_kernels._HASH_WORDS`` word rows."""
     fn = getattr(pallas_kernels, kernel)
     hlo = _compile(lambda t: fn(t.columns[:14], 42, interpret=False),
                    _table(1 << 16, one_chip))
+    assert "tpu_custom_call" in hlo
+
+
+_ENTRY_OP = re.compile(r"^\s*(?:ROOT )?%\S+ = (.+?) ([a-z][a-z\-]*)\(")
+
+
+def test_murmur3_reads_columns_as_stored(one_chip):
+    """murmur3 over all 212 columns (every kernel run) at the 64K-row
+    bucket: between the resident column buffers and the kernels lie
+    only bitcasts.  A copy or fusion there would be a relayout of a
+    column, the per-column word prep the kernel exists to avoid.  The
+    compiler may still prefetch a small operand into another memory
+    space (``copy-start``), which moves it without changing its
+    layout."""
+    hlo = _compile(lambda t: pallas_kernels.murmur3_cols(t.columns, 42),
+                   _table(1 << 16, one_chip))
+    entry = hlo[hlo.index("\nENTRY ") + 1:]
+    entry = entry[:entry.index("\n}\n")]
+    ops = {}
+    for line in entry.splitlines()[1:]:
+        m = _ENTRY_OP.match(line)
+        assert m, line[:200]
+        shape, op = m.groups()
+        ops[op] = ops.get(op, 0) + 1
+        if op == "copy-start":
+            dst, src = re.findall(r"\w+\[[\d,]*\]\{[^}]*\}", shape)[:2]
+            assert re.sub(r"S\(\d+\)", "", dst) == src, line[:200]
+    runs = pallas_kernels._mm3_runs(tuple(
+        pallas_kernels._mm3_kind(c, 0) for c in _table(8, one_chip).columns))
+    assert ops.pop("custom-call") == len(runs) > 1
+    assert set(ops) <= {"parameter", "bitcast", "copy-start", "copy-done",
+                        "tuple", "get-tuple-element"}, ops
     assert "tpu_custom_call" in hlo
 
 

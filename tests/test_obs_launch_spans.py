@@ -100,25 +100,50 @@ def pallas_on(monkeypatch):
 MIXED = [INT32, INT64, INT8, INT16, FLOAT64, BOOL8] * 6
 
 
+def _mm3_runs(cols):
+    # the kernel runs interpret-mode on the CPU
+    return pallas_kernels._mm3_runs(
+        tuple(pallas_kernels._mm3_kind(c, 0) for c in cols), True)
+
+
 def test_murmur3_pallas_launch_spans(tmp_path, pallas_on):
+    """Fixed-width columns go to the kernel as stored: a call launches
+    one program per kernel run and nothing else, each ``hash.kernel``
+    span naming its run and the columns it read from their own buffers
+    (``native_cols``) or through a prep program (``prep_cols``)."""
     t = _table(MIXED, 1024)
     found, execs = _capture(tmp_path, lambda: murmur3_hash(t))
     _check_launches(found, execs, "murmur3_hash")
     [(_, a, b, meta)] = _named(found, "murmur3_hash")
     assert meta["rows"] == 1024
     assert all(a <= s and e <= b for n, s, e, _ in found if n in LAUNCHES)
-    preps = _named(found, "hash.prep")
-    assert [m["column"] for *_, m in preps] == list(range(len(MIXED)))
-    runs = pallas_kernels._hash_runs(t.columns, 0, "mm3")
-    kernels = _named(found, "hash.kernel")
+    runs = _mm3_runs(t.columns)
     assert len(runs) > 1
-    assert [m["run"] for *_, m in kernels] == list(range(len(runs)))
-    assert [m["words"] for *_, m in kernels] == [
-        pallas_kernels._desc_rows(pallas_kernels._hash_desc(r, 0, "mm3"))
-        for r in runs]
-    assert {m["program"] for *_, m in kernels} == {"jit__mm3_call"}
-    assert [n for n, *_ in found if n in ("hash.seed", "hash.finish")] == [
-        "hash.seed", "hash.finish"]
+    assert [n for n, *_ in found if n in LAUNCHES] == (
+        ["hash.kernel"] * len(runs))
+    kernels = _named(found, "hash.kernel")
+    assert [(m["run"], m["native_cols"], m["prep_cols"])
+            for *_, m in kernels] == [(i, e - s, 0)
+                                      for i, (s, e) in enumerate(runs)]
+    assert sum(m["native_cols"] for *_, m in kernels) == len(MIXED)
+    assert {m["program"] for *_, m in kernels} == {"jit__mm3_run"}
+
+
+def test_murmur3_string_launch_spans(tmp_path, pallas_on):
+    """A dense-padded string column keeps one prep program, launched
+    before the kernel run that reads it and counted in its
+    ``prep_cols``."""
+    t = _table([INT32, INT64], 256)
+    scol = Column.strings_padded(["ab", None, "cde", ""] * 64)
+    cols = (t.columns[0], scol, t.columns[1])
+    found, execs = _capture(tmp_path, lambda: murmur3_hash(cols))
+    _check_launches(found, execs, "murmur3_hash")
+    assert [(n, m["program"]) for n, *_, m in found if n in LAUNCHES] == [
+        ("hash.prep", "jit__mm3_string_prep"), ("hash.kernel", "jit__mm3_run")]
+    [(*_, prep)] = _named(found, "hash.prep")
+    [(*_, kernel)] = _named(found, "hash.kernel")
+    assert prep["column"] == 1
+    assert (kernel["native_cols"], kernel["prep_cols"]) == (2, 1)
 
 
 def test_xxhash64_pallas_launch_spans(tmp_path, pallas_on):
@@ -197,14 +222,16 @@ def test_program_names_match_lowerings():
     name of the module the callable lowers to."""
     t = _table([INT32, INT64], 256)
     col = t.columns[0]
+    scol = Column.strings_padded(["ab", None, "cde"])
     layout = compute_row_layout(t.dtypes)
-    h = jnp.zeros((1, 256), jnp.uint32)
     gc = row_mxu.table_to_grouped(_table([INT32, INT64], 1024), layout)
     encode = row_mxu._grouped_encode_fn(jax.devices()[0])
     cases = [
-        (pallas_kernels._hash_prep, (col, 0, "mm3")),
-        (pallas_kernels._mm3_seed, (256, np.uint32(42))),
-        (pallas_kernels._mm3_finish, (h,)),
+        (pallas_kernels._hash_prep, (col, 0, "xx64")),
+        (pallas_kernels._mm3_run, (((col.data, col.validity),), None,
+                                   (pallas_kernels._mm3_kind(col, 0),),
+                                   42, 256, True, True)),
+        (pallas_kernels._mm3_string_prep, (scol, 4, 8192)),
         (pallas_kernels._xx64_seed, (256, np.uint32(42))),
         (pallas_kernels._xx64_finish, (jnp.zeros((2, 256), jnp.uint32),)),
         (_murmur3_jit, (t.columns, 42, 0)),
@@ -263,7 +290,7 @@ def test_recorded_hash_call_reads_memory_once(monkeypatch, pallas_on):
         obs.clear()
     assert len(reads) == 2 and len(resets) == 1   # entry start and end
     launches = [e for e in evs if e["name"] in LAUNCHES]
-    assert len(launches) > len(MIXED)
+    assert len(launches) == len(_mm3_runs(t.columns))
     assert not any("mem" in e for e in launches)
     [entry] = [e for e in evs if e["name"] == "murmur3_hash"]
     assert entry["mem"]["peak_delta_bytes"] == 1

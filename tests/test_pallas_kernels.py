@@ -15,6 +15,7 @@ import pytest
 
 from spark_rapids_jni_tpu.table import (
     Table, Column, BOOL8, FLOAT32, FLOAT64, INT8, INT16, INT32, INT64,
+    UINT8, UINT16, UINT64,
 )
 from spark_rapids_jni_tpu.ops import row_conversion as rc
 from spark_rapids_jni_tpu.utils import FIXED_WIDTH_DTYPES, cycle_dtypes
@@ -259,6 +260,197 @@ def test_murmur3_pallas_byte_identity(n, pattern):
     np.testing.assert_array_equal(want, got)
 
 
+# the reference benchmark's nine cudf types, then both floats
+CYCLE_DTYPES = [INT8, INT32, INT16, INT64, INT32, BOOL8, UINT16, UINT8,
+                UINT64, FLOAT32, FLOAT64]
+
+_NAN_BITS = {4: [0x7FC00001, 0xFFC00000, 0x7F800001],
+             8: [0x7FF0000000000001, 0xFFF8000000000000]}
+
+
+def _edge_values(rng, dt, n):
+    """Random values of ``dt`` with Spark's edge cases planted: -0.0,
+    0.0 and NaNs of several bit patterns for floats; the type's minimum
+    (INT_MIN), maximum and all-ones (0xFF, 0xFFFF) for integers."""
+    np_dt = dt.np_dtype
+    if np_dt.kind == "f":
+        vals = rng.standard_normal(n).astype(np_dt)
+        vals[0::6] = -0.0
+        vals[1::6] = 0.0
+        ui = np.dtype(f"u{np_dt.itemsize}")
+        for j, bits in enumerate(_NAN_BITS[np_dt.itemsize]):
+            vals[2 + j::6] = np.array(bits, ui).view(np_dt)
+        return vals
+    if dt.kind == "bool8":
+        return rng.integers(0, 2, n).astype(np_dt)
+    info = np.iinfo(np_dt)
+    vals = rng.integers(info.min, info.max, n, dtype=np_dt, endpoint=True)
+    vals[0::5] = info.min
+    vals[1::5] = info.max
+    vals[2::5] = np.array(-1).astype(np_dt)        # all ones
+    vals[3::5] = 0
+    return vals
+
+
+def _np_rotl(x, r):
+    return (x << np.uint32(r)) | (x >> np.uint32(32 - r))
+
+
+def _np_mix(h, k):
+    k = _np_rotl(k * np.uint32(0xCC9E2D51), 15) * np.uint32(0x1B873593)
+    return _np_rotl(h ^ k, 13) * np.uint32(5) + np.uint32(0xE6546B64)
+
+
+def _np_fmix(h, length):
+    h = h ^ np.uint32(length)
+    h = (h ^ (h >> np.uint32(16))) * np.uint32(0x85EBCA6B)
+    h = (h ^ (h >> np.uint32(13))) * np.uint32(0xC2B2AE35)
+    return h ^ (h >> np.uint32(16))
+
+
+def _spark_mm3(sample, seed=42):
+    """Plain Spark ``hash()`` over ``[(values, valid)]``: murmur3_x86_32
+    chained over the columns, a null field skipped; narrow ints as one
+    sign-extended int (unsigned ones zero-extended), 8-byte values as a
+    long, floats as their bits with -0.0 -> 0.0 and NaN canonical."""
+    h = np.full(len(sample[0][0]), seed, np.uint32)
+    with np.errstate(over="ignore"):
+        for vals, valid in sample:
+            if vals.dtype.kind == "f":
+                vals = np.where(vals == 0, vals.dtype.type(0), vals)
+                vals = np.where(np.isnan(vals), vals.dtype.type(np.nan),
+                                vals)
+            if vals.dtype.itemsize == 8:
+                u = vals.view(np.uint64)
+                lo = (u & np.uint64(0xFFFFFFFF)).astype(np.uint32)
+                hc = _np_fmix(_np_mix(_np_mix(h, lo), (u >> np.uint64(
+                    32)).astype(np.uint32)), 8)
+            elif vals.dtype.kind == "f":
+                hc = _np_fmix(_np_mix(h, vals.view(np.uint32)), 4)
+            else:
+                hc = _np_fmix(_np_mix(h, vals.astype(np.int64).astype(
+                    np.uint32)), 4)
+            h = hc if valid is None else np.where(valid, hc, h)
+    return h.view(np.int32)
+
+
+def _spark_mm3_bytes(h, data: bytes):
+    """Spark ``hashUnsafeBytes`` of one row from the running hash ``h``
+    (uint32): 4-byte little-endian blocks, then each tail byte
+    sign-extended as a block of its own."""
+    nb = len(data) // 4
+    with np.errstate(over="ignore"):
+        for i in range(nb):
+            h = _np_mix(h, np.frombuffer(data[4 * i:4 * i + 4], "<u4")[0])
+        for t in data[4 * nb:]:
+            h = _np_mix(h, np.int64(t - 256 if t >= 128 else t).astype(
+                np.uint32))
+        return _np_fmix(h, len(data))
+
+
+def _edge_table(rng, dtypes, n, null_rate):
+    sample, cols = [], []
+    for dt in dtypes:
+        vals = _edge_values(rng, dt, n)
+        valid = None if null_rate is None else rng.random(n) >= null_rate
+        sample.append((vals, valid))
+        cols.append(Column.from_numpy(vals, dt, valid))
+    return tuple(cols), sample
+
+
+@pytest.mark.parametrize("null_rate", [None, 0.0, 0.01, 1.0],
+                         ids=["no_validity", "nulls0", "nulls1pct",
+                              "nulls100"])
+def test_murmur3_dense_edge_values(x64_both, null_rate):
+    """The kernel reading the columns as stored equals the XLA chain and
+    the plain Spark hash over every cycle dtype and both floats, with
+    -0.0, NaNs, INT_MIN and all-ones values, at every null rate; the
+    rows are bucket-padded (1,000 -> 1,024), short of a whole chunk."""
+    rng = np.random.default_rng(900)
+    cols, sample = _edge_table(rng, CYCLE_DTYPES, 1000, null_rate)
+    b = shapes.bucket_rows(1000)
+    pcols = tuple(shapes.pad_column(c, b) for c in cols)
+    if null_rate is None:      # the bucket's rows, still no validity
+        pcols = tuple(Column(c.dtype, c.data) for c in pcols)
+    want = np.asarray(H._murmur3_chain(pcols, 42, 0))
+    got = np.asarray(pk.murmur3_cols(pcols, 42, interpret=True))
+    np.testing.assert_array_equal(want, got)
+    np.testing.assert_array_equal(got[:1000], _spark_mm3(sample))
+
+
+@pytest.mark.parametrize("n", [1, 8191, 8192, 9000, 20000])
+def test_murmur3_dense_row_counts(n):
+    """Row counts on and off the kernel's 8,192-row chunk, unpadded:
+    the kernel pads to whole chunks itself and returns ``n`` rows."""
+    rng = np.random.default_rng(910 + n)
+    cols, sample = _edge_table(rng, CYCLE_DTYPES, n, 0.01)
+    got = np.asarray(pk.murmur3_cols(cols, 42, interpret=True))
+    assert got.shape == (n,)
+    np.testing.assert_array_equal(np.asarray(H._murmur3_chain(cols, 42, 0)),
+                                  got)
+    np.testing.assert_array_equal(got, _spark_mm3(sample))
+
+
+@pytest.mark.parametrize("valid", [False, True], ids=["plain", "nullable"])
+def test_murmur3_dense_single_int32_key(monkeypatch, valid):
+    """One INT32 key through the public entry, the exchange's shape
+    (``runtime/plan.py``): one kernel call, equal to the XLA engine and
+    the plain Spark hash."""
+    rng = np.random.default_rng(920)
+    vals = rng.integers(-2**31, 2**31, 5000, dtype=np.int64).astype(
+        np.int32)
+    vals[:3] = [-2**31, -1, 0]
+    ok = rng.random(5000) > 0.2 if valid else None
+    col = Column.from_numpy(vals, INT32, ok)
+    monkeypatch.setenv("SRJ_TPU_PALLAS", "1")
+    got = np.asarray(H.murmur3_hash([col]))
+    monkeypatch.setenv("SRJ_TPU_PALLAS", "0")
+    np.testing.assert_array_equal(np.asarray(H.murmur3_hash([col])), got)
+    np.testing.assert_array_equal(got, _spark_mm3([(vals, ok)]))
+    assert len(pk._mm3_runs((pk._mm3_kind(col, 0),))) == 1
+
+
+def test_murmur3_dense_mixed_strings(monkeypatch):
+    """Fixed-width columns around two dense-padded string columns, over
+    several chained kernel calls: each string column takes one prep
+    program, the fixed ones none, and the hash equals the XLA chain and
+    a plain Spark reference row by row."""
+    monkeypatch.setattr(pk, "_MM3_INTERPRET_COLS", 3)
+    rng = np.random.default_rng(930)
+    n = 300
+    fixed, sample = _edge_table(rng, [INT64, INT8, FLOAT32, UINT16], n,
+                                0.1)
+    strs = [_string_rows(rng, n), _string_rows(rng, n)]
+    scols = [Column.strings_padded(s) for s in strs]
+    cols = (fixed[0], scols[0], fixed[1], fixed[2], scols[1], fixed[3])
+    W = max(c.chars2d.shape[1] for c in scols)
+    b, Wb = shapes.bucket_rows(n), shapes.bucket_width(W)
+    pcols = tuple(shapes.pad_column(c, b, width=Wb) for c in cols)
+    desc = tuple(pk._mm3_kind(c, pk._string_window(Wb, "mm3"))
+                 for c in pcols)
+    assert [k for k, *_ in desc].count("str") == 2
+    assert len(pk._mm3_runs(desc, True)) == 2
+    got = np.asarray(pk.murmur3_cols(pcols, 42, W=Wb, interpret=True))
+    np.testing.assert_array_equal(
+        np.asarray(H._murmur3_jit(pcols, 42, Wb)), got)
+    order = [("f", 0), ("s", 0), ("f", 1), ("f", 2), ("s", 1), ("f", 3)]
+    want = []
+    for r in range(n):
+        h = np.uint32(42)
+        for kind, i in order:
+            if kind == "s":
+                if strs[i][r] is not None:
+                    h = _spark_mm3_bytes(h, strs[i][r].encode())
+                continue
+            vals, ok = sample[i]
+            if ok[r]:
+                h = _spark_mm3([(vals[r:r + 1], None)], seed=h)[0].view(
+                    np.uint32)
+        want.append(h)
+    np.testing.assert_array_equal(
+        got[:n], np.array(want, np.uint32).view(np.int32))
+
+
 @pytest.mark.parametrize("n", [n for n in EDGE_ROWS if n > 0])
 @pytest.mark.parametrize("pattern", ["most", "none", "plain"])
 def test_xxhash64_pallas_byte_identity(n, pattern):
@@ -272,33 +464,44 @@ def test_xxhash64_pallas_byte_identity(n, pattern):
 
 
 @pytest.mark.parametrize("mode", ["mm3", "xx64"])
-def test_hash_runs_share_compiles(mode):
-    """A wide schema hashes in chained runs of at most ``_HASH_WORDS``
-    word rows, bit-exact with the XLA chain.  The XLA word prep compiles
-    per dtype and the kernel per word layout, so a second order of the
-    same dtypes adds no prep compile, and for xxhash64 (every fixed
-    column is one 8-byte block) no kernel compile either."""
+def test_hash_runs_share_compiles(monkeypatch, mode):
+    """A wide schema hashes in chained runs, bit-exact with the XLA
+    chain.  murmur3 runs a bounded number of columns a kernel call,
+    compiled per run layout: a block of columns repeated four times
+    compiles three kernels (the first run seeds the hash, the last
+    finishes it, the two middle runs share one), and hashing it again
+    compiles none.  xxhash64 runs at most ``_HASH_WORDS`` word rows; its
+    XLA word prep compiles per dtype and the kernel per word layout, so
+    a second order of the same dtypes adds neither (every fixed column
+    is one 8-byte block)."""
     rng = np.random.default_rng(77)
-    cols = tuple(_make_cols(rng, HASH_DTYPES * 4, 64))
+    cols = tuple(_make_cols(rng, HASH_DTYPES * 4, 40))
+    if mode == "mm3":
+        monkeypatch.setattr(pk, "_MM3_INTERPRET_COLS", len(HASH_DTYPES))
+        runs = pk._mm3_runs(tuple(pk._mm3_kind(c, 0) for c in cols), True)
+        assert runs == [(a, a + len(HASH_DTYPES))
+                        for a in range(0, len(cols), len(HASH_DTYPES))]
+        pk._mm3_run.clear_cache()
+        for _ in range(2):
+            np.testing.assert_array_equal(
+                np.asarray(H._murmur3_jit(cols, 42, 0)),
+                np.asarray(pk.murmur3_cols(cols, 42, interpret=True)))
+            assert pk._mm3_run._cache_size() == 3
+        return
     shuffled = tuple(cols[i] for i in rng.permutation(len(cols)))
     runs = pk._hash_runs(cols, 0, mode)
     assert len(runs) > 1
     assert all(a is b for a, b in zip([c for r in runs for c in r], cols))
     assert all(pk._desc_rows(pk._hash_desc(r, 0, mode)) <= pk._HASH_WORDS
                for r in runs)
-    if mode == "mm3":
-        xla, fn, call = H._murmur3_jit, pk.murmur3_cols, pk._mm3_call
-    else:
-        xla, fn, call = H._xx64_jit, pk.xxhash64_cols, pk._xx64_call
     sizes = []
     for cs in (cols, shuffled):
         np.testing.assert_array_equal(
-            np.asarray(xla(cs, 42, 0)),
-            np.asarray(fn(cs, 42, interpret=True)))
-        sizes.append((pk._hash_prep._cache_size(), call._cache_size()))
-    assert sizes[1][0] == sizes[0][0]
-    if mode == "xx64":
-        assert sizes[1][1] == sizes[0][1]
+            np.asarray(H._xx64_jit(cs, 42, 0)),
+            np.asarray(pk.xxhash64_cols(cs, 42, interpret=True)))
+        sizes.append((pk._hash_prep._cache_size(),
+                      pk._xx64_call._cache_size()))
+    assert sizes[1] == sizes[0]
 
 
 @pytest.mark.parametrize("op", ["murmur3_hash", "xxhash64"])
@@ -420,17 +623,35 @@ def test_hash_pallas_skips_arrow_strings(monkeypatch):
     np.testing.assert_array_equal(want, got)
 
 
+def test_murmur3_wide_window_takes_xla(monkeypatch):
+    """A string window wider than the murmur3 kernel's VMEM allows goes
+    to the XLA chain (stamped ineligible); xxhash64's kernel, which
+    tiles by lanes, still takes it."""
+    wide = Column.strings_padded(["a" * (pk._MM3_MAX_WINDOW + 1), None])
+    icol = Column.from_numpy(np.arange(2, dtype=np.int32), INT32)
+    ok = Column.strings_padded(["a" * pk._MM3_MAX_WINDOW, None])
+    assert pk._mm3_cols_eligible((icol, ok))
+    assert not pk._mm3_cols_eligible((icol, wide))
+    assert pk.hash_cols_eligible((icol, wide))
+    monkeypatch.setenv("SRJ_TPU_PALLAS", "1")
+    assert pk.choose("murmur3_hash", "tpu", sig=(icol, wide)) == (
+        "xla", False)
+    assert pk.choose("xxhash64", "tpu", sig=(icol, wide)) == (
+        "pallas", False)
+
+
 def test_hash_string_lowering_is_tpu_legal():
-    """The string-hash mats builder (padded windows -> stacked word
-    rows) must lower without per-row dynamic-start gathers: padded
-    ``chars_window`` is a static slice, so only tiny sorted lane-index
-    gathers from byte packing may appear."""
+    """The string-hash word builders (padded windows -> word rows: the
+    murmur3 string prep, the xxhash64 mats) must lower without per-row
+    dynamic-start gathers: padded ``chars_window`` is a static slice, so
+    only tiny sorted lane-index gathers from byte packing may appear."""
     rng = np.random.default_rng(53)
     pcols, Wb = _padded_hash_cols(rng, 33)
-    for mode in ("mm3", "xx64"):
-        low = jax.jit(
-            lambda cs: pk._hash_mats(cs, Wb, mode)[0]
-        ).lower(pcols).as_text()
+    Wp = pk._string_window(Wb, "mm3")
+    for build in (
+            lambda cs: pk._mm3_string_prep(cs[0], Wp, pk._mm3_rows(33)),
+            lambda cs: pk._hash_mats(cs, Wb, "xx64")[0]):
+        low = jax.jit(build).lower(pcols).as_text()
         assert "stablehlo.dynamic_slice" not in low
         assert "dynamic_gather" not in low
         assert "stablehlo.scatter" not in low
