@@ -46,11 +46,27 @@ from spark_rapids_jni_tpu.ops.row_layout import (
     JCUDF_ROW_ALIGNMENT, MAX_BATCH_BYTES, RowLayout, compute_row_layout,
 )
 from spark_rapids_jni_tpu.utils.tracing import func_range
+from spark_rapids_jni_tpu.utils import metrics as _utils_metrics
 from spark_rapids_jni_tpu.utils import tracing
+from spark_rapids_jni_tpu.obs import metrics as _obs_metrics
 from spark_rapids_jni_tpu.obs import span_fn
 from spark_rapids_jni_tpu.obs import spans as _obs_spans
 from spark_rapids_jni_tpu.runtime import shapes
 from spark_rapids_jni_tpu.runtime import staging
+
+
+def _count_string_bytes(direction: str, nbytes: int) -> None:
+    """``srj_tpu_rows_string_bytes_total{direction}``: the string bytes a
+    row encode wrote (``encode``) or a decode read (``decode``) -- the
+    chars on the compact path; rows x window width on the padded path,
+    whose lengths live only on the device.  Counted when executing
+    eagerly, as spans are."""
+    if nbytes and _utils_metrics.eager():
+        _obs_metrics.counter(
+            "srj_tpu_rows_string_bytes_total",
+            "string bytes of JCUDF row encodes and decodes: the chars "
+            "of compact rows; rows x window width of padded rows",
+            ("direction",)).inc(int(nbytes), direction=direction)
 
 
 # ---------------------------------------------------------------------------
@@ -690,7 +706,10 @@ def convert_from_rows(rows: RowsColumn, dtypes: Sequence[DType],
             out = _convert_from_rows_impl(padded, dtypes, layout,
                                           use_pallas, impl)
         with shapes.unpad_span():
-            return slice_table(out, 0, n)
+            # one program for every column, none where the batch fills
+            # its bucket (the eager slice dispatches about 60 programs a
+            # column, seconds a 155-column batch)
+            return out if n == b else _slice_table(out, 0, n)
     return _convert_from_rows_impl(rows, dtypes, layout, use_pallas, impl)
 
 
@@ -736,9 +755,12 @@ def _convert_from_rows_impl(rows: RowsColumn, dtypes: Sequence[DType],
             pallas_kernels.stamp_impl("xla")
             return _from_rows_fixed_jit(r2d, layout)
 
-        cols = resilience.run("convert_from_rows", _primary, rows2d,
-                              sig=sig, bucket=n, impl="pallas",
-                              fallback=_twin)
+        with _obs_spans.launch("rows.decode",
+                               pallas_kernels._from_rows_planes_jit,
+                               batch=0, rows=n):
+            cols = resilience.run("convert_from_rows", _primary, rows2d,
+                                  sig=sig, bucket=n, impl="pallas",
+                                  fallback=_twin)
     elif impl == "mxu":
         from spark_rapids_jni_tpu.ops import row_mxu
         if rows.data.size != n * layout.fixed_row_size:
@@ -746,14 +768,18 @@ def _convert_from_rows_impl(rows: RowsColumn, dtypes: Sequence[DType],
                 f"row blob holds {rows.data.size} bytes but offsets "
                 f"describe {n} rows of {layout.fixed_row_size}")
         # 2-D blobs go straight in; flat wire blobs reshape inside the jit
-        cols = row_mxu.from_rows_fixed(rows.data, layout)
+        with _obs_spans.launch("rows.decode", row_mxu._from_rows_mxu_jit,
+                               batch=0, rows=n):
+            cols = row_mxu.from_rows_fixed(rows.data, layout)
     else:
         rows2d = rows.rows2d(layout.fixed_row_size)
         pallas_kernels.register(
             "convert_from_rows", sig, n,
             lambda r2d: _from_rows_fixed_jit(r2d, layout),
             (rows2d,), impl="xla")
-        cols = _from_rows_fixed_jit(rows2d, layout)
+        with _obs_spans.launch("rows.decode", _from_rows_fixed_jit,
+                               batch=0, rows=n):
+            cols = _from_rows_fixed_jit(rows2d, layout)
     return Table(tuple(cols))
 
 
@@ -960,7 +986,7 @@ def _to_rows_variable_padded(table: Table, layout: RowLayout,
 
     def encode(start=0, size=None):
         return _to_rows_padded_jit(table, layout, slot_starts, fe_pad,
-                                   row_size, jnp.int32(start), size)
+                                   row_size, np.int32(start), size)
 
     # padded rows are uniform, so the only hard batch bound is the JCUDF
     # int32-offset contract (<=2GB per blob).  Batch slicing costs a full
@@ -983,34 +1009,52 @@ def _to_rows_variable_padded(table: Table, layout: RowLayout,
                 f"got {env!r}") from None
     # MAX_BATCH_BYTES stays the unconditional bound: int32 offsets
     chunk = min(size_limit, cap, MAX_BATCH_BYTES)
-    out = []
     if len(plan_fixed_batches(n, row_size, chunk)) == 1:
-        offsets = jnp.arange(n + 1, dtype=jnp.int32) * row_size
-        return [_attach_rows_tails(
-            RowsColumn(encode(), offsets, row_size, widths),
-            _batch_string_tails(scols, 0, n))]
-    # equal-sized 32-row-aligned batches sharing one compiled program
-    # (same policy as the fixed-width path)
-    nb = -(-n * row_size // chunk)
-    per = min((-(-n // nb) + 31) // 32 * 32,
-              chunk // row_size // 32 * 32)
-    for start in range(0, n, per):
+        per = n
+    else:
+        # equal-sized 32-row-aligned batches sharing one compiled program
+        # (same policy as the fixed-width path)
+        nb = -(-n * row_size // chunk)
+        per = min((-(-n // nb) + 31) // 32 * 32,
+                  chunk // row_size // 32 * 32)
+    out = []
+    for batch, start in enumerate(range(0, n, per) if n else [0]):
         size = min(per, n - start)
-        offsets = jnp.arange(size + 1, dtype=jnp.int32) * row_size
+        with _obs_spans.launch("rows.offsets", _row_iota, batch=batch):
+            offsets = _row_iota(size + 1)
+        with _obs_spans.launch("rows.offsets", _row_scale, batch=batch):
+            offsets = _row_scale(offsets, row_size)
+        with _obs_spans.launch("rows.encode", _to_rows_padded_jit,
+                               batch=batch, rows=size, strings=len(scols),
+                               str_width=max(widths, default=0)):
+            data = encode() if size == n else encode(start, size)
         out.append(_attach_rows_tails(
-            RowsColumn(encode(start, size), offsets, row_size, widths),
+            RowsColumn(data, offsets, row_size, widths),
             _batch_string_tails(scols, start, start + size)))
+    _count_string_bytes("encode", n * sum(widths))
     return out
 
 
 @functools.partial(jax.jit, static_argnums=(1, 2, 3))
 def _from_rows_padded_jit(data: jnp.ndarray, layout: RowLayout,
                           str_widths: Tuple[int, ...],
-                          mode: str = "xla"):
+                          mode: str = "xla") -> Table:
+    """The whole decode of a padded batch, the Table included, in one
+    program (columns built outside would cost a dispatch each)."""
     row_size = padded_variable_layout(layout, str_widths)[2]
     n = data.shape[0] if data.ndim == 2 \
         else data.shape[0] // row_size
-    return padded_cols_from_rows(data, layout, str_widths, n, mode)
+    datas, masks, str_parts = padded_cols_from_rows(
+        data, layout, str_widths, n, mode)
+    empty = jnp.zeros((0,), jnp.uint8)
+    cols, parts = [], iter(str_parts)
+    for i, dt in enumerate(layout.dtypes):
+        if dt.is_string:
+            chars2d, offsets = next(parts)
+            cols.append(Column(dt, empty, masks[i], offsets, None, chars2d))
+        else:
+            cols.append(Column(dt, datas[i], masks[i]))
+    return Table(tuple(cols))
 
 
 def padded_cols_from_rows(data: jnp.ndarray, layout: RowLayout,
@@ -1055,26 +1099,20 @@ def padded_cols_from_rows(data: jnp.ndarray, layout: RowLayout,
 def _from_rows_variable_padded(rows: RowsColumn, layout: RowLayout) -> Table:
     from spark_rapids_jni_tpu.table import attach_string_tail
     from spark_rapids_jni_tpu.ops import row_mxu
+    n = rows.num_rows
     mode = "pallas" if (_platform_of(rows) == "tpu"
-                        and rows.num_rows >= row_mxu._FUSE_TILE) \
+                        and n >= row_mxu._FUSE_TILE) \
         else "xla"
-    datas, masks, str_parts = _from_rows_padded_jit(
-        rows.data, layout, rows.str_widths, mode)
+    with _obs_spans.launch("rows.decode", _from_rows_padded_jit,
+                           batch=0, rows=n):
+        out = _from_rows_padded_jit(rows.data, layout, rows.str_widths,
+                                    mode)
+    _count_string_bytes("decode", n * sum(rows.str_widths))
     tails = getattr(rows, "_string_tails", None) or {}
-    cols = []
-    si = 0
-    for i, dt in enumerate(layout.dtypes):
-        if dt.is_string:
-            chars2d, offsets = str_parts[si]
-            col = Column(dt, jnp.zeros((0,), jnp.uint8), masks[i],
-                         offsets, None, chars2d)
-            if si in tails:
-                attach_string_tail(col, tails[si])
-            si += 1
-            cols.append(col)
-        else:
-            cols.append(Column(dt, datas[i], masks[i]))
-    return Table(tuple(cols))
+    scols = [c for c in out.columns if c.dtype.is_string]
+    for si, t in tails.items():
+        attach_string_tail(scols[si], t)
+    return out
 
 
 def compact_rows_host(rows: RowsColumn, dtypes: Sequence[DType]) -> RowsColumn:
@@ -1283,26 +1321,33 @@ def _from_rows_variable(rows: RowsColumn, layout: RowLayout) -> Table:
     # everything except the (data-dependent-size) char gathers happens in
     # ONE compiled program: per-column eager dispatch would cost hundreds
     # of host round trips
-    datas, masks, f_words, str_lens = _extract_fixed_variable_jit(
-        rows.data, rows.offsets, layout)
+    n = rows.num_rows
+    with _obs_spans.launch("rows.decode", _extract_fixed_variable_jit,
+                           batch=0, rows=n):
+        datas, masks, f_words, str_lens = _extract_fixed_variable_jit(
+            rows.data, rows.offsets, layout)
     # ONE host sync for all string columns' char totals (the reference
     # syncs once per column at row_conversion.cu:2215; batching the sync
     # and the gather compile makes the data-dependent-shape cost O(1) in
     # the number of string columns)
-    totals = tuple(
-        int(x) for x in np.asarray(
-            _str_totals_jit(str_lens))) if str_lens else ()
-    str_parts = _gather_all_strings_jit(
-        rows.data, rows.offsets, f_words, tuple(layout.variable_starts),
-        str_lens, totals) if str_lens else []
+    with _obs_spans.launch("rows.decode", _str_totals_jit, batch=0,
+                           rows=n):
+        totals = tuple(int(x) for x in np.asarray(
+            _str_totals_jit(str_lens)))
+    with _obs_spans.launch("rows.decode", _gather_all_strings_jit,
+                           batch=0, rows=n):
+        str_parts = _gather_all_strings_jit(
+            rows.data, rows.offsets, f_words,
+            tuple(layout.variable_starts), str_lens, totals)
+    _count_string_bytes("decode", sum(totals))
+    empty = jnp.asarray(np.zeros((0,), np.uint8))   # a transfer, no program
     cols = []
     si = 0
     for i, dt in enumerate(layout.dtypes):
         if dt.is_string:
             chars, offsets = str_parts[si]
             si += 1
-            cols.append(Column(dt, jnp.zeros((0,), jnp.uint8), masks[i],
-                               offsets, chars))
+            cols.append(Column(dt, empty, masks[i], offsets, chars))
         else:
             cols.append(Column(dt, datas[i], masks[i]))
     return Table(tuple(cols))

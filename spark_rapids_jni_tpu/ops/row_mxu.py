@@ -602,14 +602,27 @@ def _grouped_encode_fn(dev):
                    out_shardings=fmt)
 
 
+def _not_persisted():
+    """Compile what runs inside without writing it to the persistent
+    compile cache.  An executable read back from that cache labels
+    outputs of a forced non-default layout with the default one (the
+    buffer keeps the forced layout), so every program that then reads
+    such a blob is compiled for the wrong layout and refused at launch
+    (``expected u8[n,rs]{0,1:...} but got {1,0:...}``).  Thread-local;
+    the in-memory jit cache still holds the program."""
+    from jax._src import config as jax_config
+    return jax_config.persistent_cache_min_compile_time_secs(float("inf"))
+
+
 def _grouped_encode_jit(planes, vmask, layout, size, interpret,
                         start_tiles):
     try:
         dev = next(iter(planes.devices()))
     except Exception:
         dev = jax.devices()[0]
-    return _grouped_encode_fn(dev)(planes, vmask, layout, size,
-                                   interpret, start_tiles)
+    with _not_persisted():
+        return _grouped_encode_fn(dev)(planes, vmask, layout, size,
+                                       interpret, start_tiles)
 
 
 @functools.partial(jax.jit, static_argnums=(1,))

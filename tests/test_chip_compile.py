@@ -23,7 +23,7 @@ from jax.sharding import SingleDeviceSharding
 
 from spark_rapids_jni_tpu.ops import pallas_kernels, row_mxu
 from spark_rapids_jni_tpu.ops.row_layout import compute_row_layout
-from spark_rapids_jni_tpu.table import Column, Table
+from spark_rapids_jni_tpu.table import STRING, Column, Table
 from spark_rapids_jni_tpu.utils import FIXED_WIDTH_DTYPES, cycle_dtypes
 
 HBM_BYTES = 16 * 1024 ** 3        # one v5e chip
@@ -104,6 +104,37 @@ def test_mxu_decode(one_chip, grouped):
     hlo = _compile(lambda r: fn(r, layout, "pallas"),
                    _sds((1 << 21, layout.fixed_row_size), jnp.uint8,
                         one_chip))
+    assert "tpu_custom_call" in hlo
+
+
+def _string_table(n, dev, width=32):
+    """The strings deployment's schema: 130 fixed-width columns of the
+    reference's cycle, then 25 dense-padded string columns."""
+    fixed = _table(n, dev).columns[:130]
+    strings = tuple(
+        Column(STRING, _sds((0,), jnp.uint8, dev),
+               _sds(((n + 7) // 8,), jnp.uint8, dev),
+               _sds((n + 1,), jnp.int32, dev), None,
+               _sds((n, width), jnp.uint8, dev)) for _ in range(25))
+    return Table(fixed + strings)
+
+
+@pytest.mark.parametrize("direction", ["encode", "decode"])
+def test_string_window_rows(one_chip, direction):
+    """The padded string window's encode of the 1M-row table and its
+    decode (the fused planes kernel, and the columns built in the same
+    program) at the strings deployment's widths."""
+    from spark_rapids_jni_tpu.ops import row_conversion as rc
+    t = _string_table(1 << 20, one_chip)
+    layout = compute_row_layout(t.dtypes)
+    starts, fe_pad, rs = rc.padded_variable_layout(layout, (32,) * 25)
+    if direction == "encode":
+        _compile(lambda tb: rc._to_rows_padded_jit(
+            tb, layout, starts, fe_pad, rs, 0, None), t)
+        return
+    hlo = _compile(lambda r: rc._from_rows_padded_jit(
+        r, layout, (32,) * 25, "pallas"),
+        _sds((1 << 20, rs), jnp.uint8, one_chip))
     assert "tpu_custom_call" in hlo
 
 

@@ -20,8 +20,10 @@ import pytest
 from spark_rapids_jni_tpu import (
     BOOL8, Column, FLOAT64, INT8, INT16, INT32, INT64, Table, obs,
 )
-from spark_rapids_jni_tpu.obs import spans
-from spark_rapids_jni_tpu.ops import convert_to_rows, pallas_kernels
+from spark_rapids_jni_tpu.obs import metrics, spans
+from spark_rapids_jni_tpu.ops import (
+    convert_from_rows, convert_to_rows, pallas_kernels,
+)
 from spark_rapids_jni_tpu.ops import row_conversion, row_mxu
 from spark_rapids_jni_tpu.ops.hashing import (
     murmur3_hash, pmod, xxhash64, _murmur3_jit, _xx64_jit,
@@ -29,7 +31,8 @@ from spark_rapids_jni_tpu.ops.hashing import (
 from spark_rapids_jni_tpu.ops.row_layout import compute_row_layout
 
 LAUNCHES = ("hash.prep", "hash.kernel", "hash.xla", "hash.seed",
-            "hash.finish", "rows.pack", "rows.encode", "rows.offsets")
+            "hash.finish", "rows.pack", "rows.encode", "rows.offsets",
+            "rows.decode")
 
 
 def _table(dtypes, n, seed=0):
@@ -307,3 +310,150 @@ def test_no_capture_opens_no_annotation(monkeypatch):
     with obs.span("x"):
         pass
     assert opened == []
+
+
+def _string_table(n, fixed=(INT32, INT64, INT8), strings=2, seed=0):
+    """Fixed-width columns, then dense-padded string columns of 0-12
+    bytes (a 12-byte window)."""
+    rng = np.random.default_rng(seed)
+    t = _table(fixed, n, seed)
+    scols = tuple(Column.strings_padded(
+        [None if rng.random() < 0.1 else "x" * int(rng.integers(0, 13))
+         for _ in range(n)], pad_to=12) for _ in range(strings))
+    return Table(t.columns + scols)
+
+
+def test_var_rows_encode_span(tmp_path):
+    """The padded string window's encode is one launch a batch; the
+    offsets are the two programs the fixed-width batches launch."""
+    t = _string_table(256)
+    found, execs = _capture(tmp_path, lambda: convert_to_rows(t))
+    _check_launches(found, execs, "convert_to_rows")
+    [(*_, meta)] = _named(found, "rows.encode")
+    assert (meta["program"], meta["batch"], meta["rows"], meta["strings"],
+            meta["str_width"]) == ("jit__to_rows_padded_jit", 0, 256, 2, 12)
+    assert [m["program"] for *_, m in _named(found, "rows.offsets")] == [
+        "jit__row_iota", "jit__row_scale"]
+
+
+def _fixed_rows(impl):
+    t = _table([INT32, INT64, INT8, INT16], 256)
+    return convert_to_rows(t, impl=impl)[0], t.dtypes
+
+
+def _padded_rows():
+    t = _string_table(256)
+    return convert_to_rows(t)[0], t.dtypes
+
+
+@pytest.mark.parametrize("make,impl,program", [
+    (lambda: _fixed_rows("xla"), "xla", "jit__from_rows_fixed_jit"),
+    (lambda: _fixed_rows("mxu"), "mxu", "jit__from_rows_mxu_jit"),
+    (_padded_rows, None, "jit__from_rows_padded_jit"),
+], ids=["fixed_xla", "fixed_mxu", "var_padded"])
+def test_convert_from_rows_decode_span(tmp_path, make, impl, program):
+    """A batch on the bucket grid decodes in one launch span that runs
+    the program it names, and runs nothing else."""
+    rows, dtypes = make()
+    found, execs = _capture(
+        tmp_path, lambda: convert_from_rows(rows, dtypes, impl=impl))
+    [(_, s, e, meta)] = _named(found, "rows.decode")
+    assert (meta["program"], meta["batch"], meta["rows"]) == (program, 0,
+                                                              256)
+    assert [m for t, m in execs if s <= t < e] == [program]
+    _check_launches(found, execs, "convert_from_rows")
+    [(_, a, b, _)] = _named(found, "convert_from_rows")
+    assert len([t for t, _ in execs if a <= t < b]) == 1
+
+
+def test_compact_var_decode_spans(tmp_path):
+    """Compact rows decode in three launches: the fixed sections, the
+    char totals the host reads, the char gathers."""
+    padded, dtypes = _padded_rows()
+    rows = row_conversion.compact_rows_host(padded, dtypes)
+    found, execs = _capture(tmp_path,
+                            lambda: convert_from_rows(rows, dtypes))
+    _check_launches(found, execs, "convert_from_rows")
+    assert [(m["program"], m["rows"])
+            for *_, m in _named(found, "rows.decode")] == [
+        ("jit__extract_fixed_variable_jit", 256),
+        ("jit__str_totals_jit", 256),
+        ("jit__gather_all_strings_jit", 256)]
+
+
+@pytest.mark.parametrize("n", [256, 200], ids=["on_grid", "off_grid"])
+def test_padded_var_decode_programs_do_not_grow_with_columns(tmp_path, n):
+    """The decode of a padded string batch launches the same few
+    programs at 5 columns and at 60 -- never a program a column: on the
+    bucket grid the decode alone; off it also the pad to the bucket (a
+    zero scratch and the donated fill) and the cut back to the batch's
+    rows."""
+    counts = []
+    for fixed, strings in (((INT32, INT8, INT64), 2),
+                           ((INT32, INT8, INT64, INT16) * 10, 20)):
+        t = _string_table(n, fixed, strings)
+        [rows] = convert_to_rows(t)
+        found, execs = _capture(
+            tmp_path / str(strings),
+            lambda: convert_from_rows(rows, t.dtypes))
+        [(_, a, b, _)] = _named(found, "convert_from_rows")
+        counts.append(len([t for t, _ in execs if a <= t < b]))
+        assert len(_named(found, "rows.decode")) == 1
+    assert counts[0] == counts[1] <= (1 if n == 256 else 5), counts
+
+
+@pytest.mark.parametrize("n", [256, 200], ids=["on_grid", "off_grid"])
+def test_fixed_decode_programs_do_not_grow_with_columns(tmp_path, n):
+    """A fixed-width batch decodes in the same few programs at 4 columns
+    and at 40: the decode, and off the bucket grid the pad and one cut
+    back to the batch's rows (no eager slice a column)."""
+    counts = []
+    for dtypes in ((INT32, INT64, INT8, INT16),
+                   (INT32, INT64, INT8, INT16) * 10):
+        t = _table(dtypes, n)
+        [rows] = convert_to_rows(t, impl="mxu")
+        found, execs = _capture(
+            tmp_path / str(len(dtypes)),
+            lambda: convert_from_rows(rows, t.dtypes, impl="mxu"))
+        [(_, a, b, _)] = _named(found, "convert_from_rows")
+        counts.append(len([t for t, _ in execs if a <= t < b]))
+        assert len(_named(found, "rows.decode")) == 1
+    assert counts[0] == counts[1] <= (1 if n == 256 else 5), counts
+
+
+def _string_bytes():
+    vals = metrics.registry().snapshot().get(
+        "srj_tpu_rows_string_bytes_total", {"values": {}})["values"]
+    return {k: vals.get(f"direction={k}", 0) for k in ("encode", "decode")}
+
+
+def test_string_bytes_counter():
+    """Each encode and decode of a padded batch counts its string
+    windows: rows x window width over the string columns."""
+    t = _string_table(256)
+    before = _string_bytes()
+    [rows] = convert_to_rows(t)
+    convert_from_rows(rows, t.dtypes)
+    after = _string_bytes()
+    assert after["encode"] - before["encode"] == 256 * 2 * 12
+    assert after["decode"] - before["decode"] == 256 * 2 * 12
+
+
+def test_decode_program_names_match_lowerings():
+    t = _string_table(256)
+    layout = compute_row_layout(t.dtypes)
+    [rows] = convert_to_rows(t)
+    starts, fe_pad, row_size = row_conversion.padded_variable_layout(
+        layout, rows.str_widths)
+    flayout = compute_row_layout([INT32, INT64])
+    frows = jnp.zeros((256, flayout.fixed_row_size), jnp.uint8)
+    cases = [
+        (row_conversion._to_rows_padded_jit,
+         (t, layout, starts, fe_pad, row_size, np.int32(0), None)),
+        (row_conversion._from_rows_padded_jit,
+         (rows.data, layout, rows.str_widths, "xla")),
+        (row_conversion._from_rows_fixed_jit, (frows, flayout)),
+        (row_mxu._from_rows_mxu_jit, (frows, flayout, "xla")),
+    ]
+    for fn, args in cases:
+        assert _module_name(fn, *args) == spans.program_name(fn)

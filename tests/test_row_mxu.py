@@ -260,3 +260,38 @@ def test_transpose_engine_matches_dot_engine(rng):
         a = np.asarray(row_mxu.to_rows_fixed_grouped(gc, interpret=True))
         b = np.asarray(row_mxu.to_rows_fixed_grouped_transpose(gc))
         np.testing.assert_array_equal(a, b)
+
+
+def test_grouped_encode_is_not_written_to_the_compile_cache(rng, tmp_path):
+    """The grouped encode forces its blob row-major.  JAX reads such an
+    executable back from the persistent compile cache labelling the blob
+    with the default layout, and every program that then reads the blob
+    is refused at launch, so the encode is compiled in each process and
+    never written there; the pack before it is cached as usual."""
+    import os
+    import jax
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    from spark_rapids_jni_tpu.ops import row_mxu
+    from spark_rapids_jni_tpu.ops.row_layout import compute_row_layout
+    # a schema no other test encodes, so both programs compile here
+    t = _random_table(rng, [INT16, UINT8, INT64, BOOL8, INT16, UINT8, INT8],
+                      row_mxu._FUSE_TILE)
+    layout = compute_row_layout(t.dtypes)
+    keys = ("jax_compilation_cache_dir",
+            "jax_persistent_cache_min_compile_time_secs",
+            "jax_persistent_cache_min_entry_size_bytes")
+    was = {k: getattr(jax.config, k) for k in keys}
+    try:
+        cc.reset_cache()
+        for k, v in zip(keys, (str(tmp_path), 0.0, -1)):
+            jax.config.update(k, v)
+        enc = row_mxu.FixedEncoder(t, layout, interpret=True)
+        blob = jax.block_until_ready(enc.encode(0, t.num_rows))
+        written = os.listdir(tmp_path)
+    finally:
+        for k, v in was.items():
+            jax.config.update(k, v)
+        cc.reset_cache()
+    assert blob.shape == (t.num_rows, layout.fixed_row_size)
+    assert any(f.startswith("jit__pack_grouped_jit-") for f in written)
+    assert not any("grouped_encode" in f for f in written), written

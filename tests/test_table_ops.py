@@ -1038,3 +1038,21 @@ def test_aggregate_empty_source():
         max_groups=8)
     assert int(np.asarray(ng)) == 0
     assert not np.asarray(have).any()
+
+
+@pytest.mark.parametrize("start,end", [(0, 77), (0, 80), (8, 77), (16, 16),
+                                       (3, 61), (5, 77)])
+def test_slice_table_validity_bits(start, end):
+    """A row slice's packed validity is the slice of the bits, packed
+    from bit 0 with every bit past it zero: cut as bytes from a byte
+    boundary, repacked from any other start."""
+    from spark_rapids_jni_tpu.table import slice_table
+    rng = np.random.default_rng(start * 100 + end)
+    valid = rng.random(77) > 0.3
+    t = Table((Column.from_numpy(np.arange(77, dtype=np.int32), INT32,
+                                 valid=valid),))
+    c = slice_table(t, start, end).columns[0]
+    want = np.packbits(valid[start:end], bitorder="little")
+    np.testing.assert_array_equal(np.asarray(c.validity), want)
+    np.testing.assert_array_equal(np.asarray(c.data),
+                                  np.arange(77)[start:end])
